@@ -524,6 +524,10 @@ def validate(spec: PipelineSpec) -> None:
                         f" (derived from the level-{lvl - 1} ceiling × "
                         f"fan-in) — lower budget.sample_sizes[{lvl}] or "
                         f"raise the downstream ceilings"))
+    if samp.backend == "pallas_fused":
+        import jax
+
+        check_kernel_envelope(spec, jax.default_backend())
     # Error-budget feasibility: the controller grows SAMPLE budgets, but a
     # quantile sketch's rank-error floor is set by its CAPACITY (the leveled
     # compaction schedule) — no sample budget can push the published bound
@@ -545,3 +549,49 @@ def validate(spec: PipelineSpec) -> None:
                          f"above budget.target_rel_error={target}; the "
                          f"error-budget controller could never settle. "
                          f"Raise the sketch capacity or relax the target.")
+
+
+def check_kernel_envelope(spec: PipelineSpec, platform: str) -> None:
+    """Refuse a spec whose sampler kernel cannot compile its shapes on
+    ``platform``. The ``pallas_fused`` tick keeps a node's whole buffer
+    VMEM-resident, so on TPU it compiles only inside its per-node
+    envelope; nothing falls back to another sampler. Off TPU the kernels
+    run in the Pallas interpreter, which has no such bound."""
+    if platform != "tpu" or spec.sampler.backend != "pallas_fused":
+        return
+    from repro.core.tree import derive_capacities
+
+    topo = spec.topology
+    _, max_sizes = derive_sample_sizes(spec)
+    caps = derive_capacities(list(topo.fanin), topo.capacity,
+                             list(max_sizes),
+                             list(topo.interval_ticks or (1,) * topo.n_levels))
+    for lvl, cap in enumerate(caps):
+        check_fused_envelope(cap, topo.num_strata, platform,
+                             f"a level-{lvl} node buffer")
+
+
+def check_fused_envelope(items: int, num_strata: int, platform: str,
+                         what: str) -> None:
+    """Refuse one ``pallas_fused`` kernel call that cannot compile on
+    ``platform``: the fused tick and the fused selection hold their
+    whole input in VMEM, and on TPU compile for at most ``MAX_CAPACITY``
+    items and ``MAX_STRATA`` strata. Spec validation checks the tree's
+    node buffers; the selection checks itself when traced, since a mesh
+    shard's width and the mesh root's gathered batch are known only
+    then."""
+    if platform != "tpu":
+        return
+    from repro.kernels.fused_level_tick.fused_level_tick import (
+        MAX_CAPACITY, MAX_STRATA)
+
+    fix = (" — use sampler.backend 'topk' or 'pallas', which stream any "
+           "buffer size")
+    _require(num_strata <= MAX_STRATA,
+             f"sampler.backend='pallas_fused' compiles on TPU for at most "
+             f"{MAX_STRATA} strata per node; {what} has {num_strata}{fix}")
+    _require(items <= MAX_CAPACITY,
+             f"sampler.backend='pallas_fused' keeps its whole input in VMEM "
+             f"and compiles on TPU for at most {MAX_CAPACITY} items; {what} "
+             f"holds {items} — lower topology.capacity, the budget "
+             f"ceilings or the items per device{fix}")

@@ -42,26 +42,15 @@ from repro.core.types import IntervalBatch
 from repro.launch.sharding import spmd_epoch_specs, spmd_query_epoch_specs
 
 
-def _shard_map():
-    try:
-        return jax.shard_map                       # jax >= 0.6
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map
-
-
-def _rep_check_kwargs(fn, enabled: bool) -> dict:
-    """The replication-check kwarg was renamed ``check_rep`` →
-    ``check_vma`` across jax versions; pass whichever this build has."""
-    import inspect
-
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):                # pragma: no cover
-        params = {}
-    name = "check_vma" if "check_vma" in params else "check_rep"
-    return {name: enabled}
+def _check_vma(backend: str) -> bool:
+    """Whether the epoch's ``shard_map`` type-checks varying axes. Every
+    kernel output declares its varying axes (``kernels.out_struct``), so
+    on TPU the check is on for every sampler backend. Off TPU the Pallas
+    sampler backends run in the Pallas interpreter, which in JAX 0.9.0
+    mis-types its block slices under the check, so there it is off for
+    them; the check changes no value."""
+    return (backend not in ("pallas", "pallas_fused")
+            or jax.default_backend() == "tpu")
 
 
 class SpmdPipelineState(NamedTuple):
@@ -105,8 +94,6 @@ def _spmd_program_entry(mesh, axis_name, core, max_budget, num_strata,
         _SPMD_PROGRAM_STATS["hits"] += 1
         return sig, entry
     _SPMD_PROGRAM_STATS["misses"] += 1
-    sm = _shard_map()
-    rep_kw = _rep_check_kwargs(sm, backend != "pallas")
     counter = {"traces": 0}
     parts = spmd_query_epoch_specs(axis_name, core.init_state())
     # The telemetry leaves are replicated by construction (psum-derived);
@@ -156,10 +143,12 @@ def _spmd_program_entry(mesh, axis_name, core, max_budget, num_strata,
             telemetry=tel)
         return state2, (ts,) + outs
 
-    fn = sm(epoch, mesh=mesh,
-            in_specs=(state_spec, parts["replicated"],
-                      parts["replicated"], parts["batches"]),
-            out_specs=(state_spec, parts["replicated"]), **rep_kw)
+    fn = jax.shard_map(
+        epoch, mesh=mesh,
+        in_specs=(state_spec, parts["replicated"], parts["replicated"],
+                  parts["batches"]),
+        out_specs=(state_spec, parts["replicated"]),
+        check_vma=_check_vma(backend))
     entry = {"fn": jax.jit(fn, donate_argnums=(0,)),
              "trace_counter": counter}
     _SPMD_PROGRAM_CACHE[sig] = entry
@@ -195,11 +184,7 @@ class CompiledSpmdPipeline(QueryRouting):
         self.root_budget = int(r.sample_sizes[-1])
         self.telemetry_enabled = spec.telemetry.enabled
         self.trace_counter = {"traces": 0}
-        sm = _shard_map()
-        # pallas_call has no replication rule under shard_map's rep/vma
-        # check — the kernel backend opts out (results are still
-        # replicated by construction, see spmd_local_then_root).
-        rep_kw = _rep_check_kwargs(sm, spec.sampler.backend != "pallas")
+        check_vma = _check_vma(spec.sampler.backend)
         if self.plan is not None:
             # Tenant lowering: merged-summary query plane. Spec
             # validation already guarantees mode == "whs" here (tenants
@@ -221,8 +206,8 @@ class CompiledSpmdPipeline(QueryRouting):
                 return T.spmd_srs_epoch(key, batches, axis_name=axis_name,
                                         fraction=frac)
 
-            fn = sm(srs_epoch, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, **rep_kw)
+            fn = jax.shard_map(srs_epoch, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=check_vma)
             self._fn = jax.jit(fn)
         else:
             in_specs, out_specs = spmd_epoch_specs(axis_name)
@@ -232,9 +217,10 @@ class CompiledSpmdPipeline(QueryRouting):
                       root_budget=self.root_budget,
                       allocation=spec.sampler.allocation,
                       sampler_backend=spec.sampler.backend)
-            fn = sm(lambda k, b: T.spmd_local_then_root_epoch(k, b, **kw),
-                    mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    **rep_kw)
+            fn = jax.shard_map(
+                lambda k, b: T.spmd_local_then_root_epoch(k, b, **kw),
+                mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                check_vma=check_vma)
             self._fn = jax.jit(fn)
 
     # ---------------------------------------------------- tenant churn --

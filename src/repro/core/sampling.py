@@ -414,14 +414,16 @@ class PallasFusedBackend:
     ``whs.level_tick``), collapsing sample + weight-update + compaction
     into one kernel launch per level.
 
-    The dense one-hot working set is ``O(M·X)`` VMEM per problem, so
-    selection falls back to ``argsort`` beyond ``_DENSE_LIMIT``.
+    The one-hot working set is ``O(M·X)`` VMEM per problem; on TPU the
+    kernel compiles within ``kernels.fused_level_tick``'s envelope, and
+    ``api.spec.validate`` refuses specs outside it. ``select`` checks its
+    own shape when traced (a mesh shard, the mesh root's gathered
+    batch) and raises the same ``SpecError`` outside the envelope.
     """
 
     name = "pallas_fused"
     flatten_for_level = True
     fused_level_tick = True
-    _DENSE_LIMIT = 1 << 22
 
     def counts(self, stratum, valid, num_strata):
         from repro.kernels.stratified_stats import ops as ss_ops
@@ -434,16 +436,16 @@ class PallasFusedBackend:
 
     def select(self, key, stratum, valid, reservoirs, num_strata, *,
                priorities=None, max_reservoir=None, batch_hint=1):
+        from repro.api.spec import check_fused_envelope
         from repro.kernels.fused_level_tick import ops as ft_ops
 
-        m = stratum.shape[0]
+        check_fused_envelope(stratum.shape[0], num_strata,
+                             jax.default_backend(),
+                             "this selection (one node's buffer, one "
+                             "device's mesh shard or the mesh root's "
+                             "gathered reservoirs)")
         if priorities is None:
-            priorities = jax.random.uniform(key, (m,))
-        if max(int(batch_hint), 1) * num_strata * m > self._DENSE_LIMIT:
-            return stratified_priority_sample(
-                key, stratum, valid, reservoirs, num_strata,
-                priorities=priorities,
-            )
+            priorities = jax.random.uniform(key, (stratum.shape[0],))
         return ft_ops.fused_select(priorities, stratum, valid, reservoirs,
                                    num_strata, impl="pallas")
 

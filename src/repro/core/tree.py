@@ -1164,9 +1164,11 @@ def spmd_local_then_root(
     Call under ``shard_map`` with ``axis_name`` bound, e.g. the "data"
     axis; every device computes the root stage redundantly (no single
     point of failure, no coordination — §III-E). ``sampler_backend``
-    selects the selection engine at both stages; with ``"pallas"`` the
-    enclosing ``shard_map`` must pass ``check_rep=False`` (JAX has no
-    replication rule for ``pallas_call``).
+    selects the selection engine at both stages; off TPU, with the
+    Pallas backends, the enclosing ``shard_map`` passes
+    ``check_vma=False`` (their kernels then run in the Pallas
+    interpreter, which in JAX 0.9.0 mis-types its block slices under the
+    vma check; see ``api.spmd._check_vma``).
     """
     # Local stage: per-device key. Root stage: the SAME key on every device
     # so the redundantly-computed root result is bit-identical (replicated).

@@ -34,8 +34,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import out_struct, row_spec
+
 _BLOCK_ITEMS = 4096
-# f32 elements of in-kernel one-hot tile the cms kernel may materialize
+# f32 elements of in-kernel one-hot tile a kernel may materialize
 # per grid step (~4 MiB) — well under a TPU core's ~16 MiB VMEM once the
 # item tiles and the [depth, width] accumulator are co-resident.
 _ONEHOT_BUDGET_ELEMS = 1 << 20
@@ -62,9 +64,13 @@ def _cms_kernel(keys_ref, w_ref, mult_ref, out_ref, *, depth: int,
         bucket = jax.lax.shift_right_logical(
             k * mult_ref[0, d], jnp.uint32(shift)).astype(jnp.int32)
         onehot = jnp.where(bucket[:, None] == cols, 1.0, 0.0)
+        # f32 precision: the default TPU contraction may round the
+        # summed weights to bf16.
         row = jax.lax.dot_general(                         # [1,B] @ [B,W]
             w[None, :], onehot,
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
         out_ref[d, :] += row[0, :]
 
 
@@ -100,18 +106,15 @@ def cms_update(
     n = keys.shape[0] // block
     mult = jnp.asarray(HASH_MULTIPLIERS[:depth], jnp.uint32).reshape(1, depth)
 
+    row = row_spec(block)
     return pl.pallas_call(
         functools.partial(_cms_kernel, depth=depth, width=width, shift=shift),
         grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, depth), lambda i: (0, 0)),
-        ],
+        in_specs=[row, row, pl.BlockSpec((1, depth), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((depth, width), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((depth, width), jnp.float32),
+        out_shape=out_struct((depth, width), jnp.float32, keys, weights),
         interpret=interpret,
-    )(keys.reshape(n, block), weights.reshape(n, block), mult)
+    )(keys.reshape(n, 1, block), weights.reshape(n, 1, block), mult)
 
 
 def _compact_kernel(vals_ref, cwp_ref, cw_ref, tgt_ref, out_ref, *,
@@ -128,8 +131,10 @@ def _compact_kernel(vals_ref, cwp_ref, cw_ref, tgt_ref, out_ref, *,
     t = tgt_ref[0, :]                                      # f32[C]
     hit = jnp.where((lo[:, None] <= t[None, :]) & (t[None, :] < hi[:, None]),
                     1.0, 0.0)                              # f32[B, C]
+    # f32 precision: the picked value must come back bit-for-bit.
     picked = jax.lax.dot_general(                          # [1,B] @ [B,C]
         v[None, :], hit, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     out_ref[...] += picked
 
@@ -152,7 +157,9 @@ def quantile_compact(
     """
     p_items = values.shape[0]
     n_targets = targets.shape[0]
-    block = min(_BLOCK_ITEMS, p_items)
+    # Same VMEM cap as the cms kernel, on the [block, C] membership tile.
+    block = min(_BLOCK_ITEMS, max(256, _ONEHOT_BUDGET_ELEMS // n_targets),
+                p_items)
     pad = (-p_items) % block
     if pad:
         # padded slots get an empty interval at the very top: lo == hi == W
@@ -164,17 +171,15 @@ def quantile_compact(
         cumw = cumw.at[p_items:].set(top)
     n = values.shape[0] // block
 
+    row = row_spec(block)
     return pl.pallas_call(
         functools.partial(_compact_kernel, n_targets=n_targets),
         grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_targets), lambda i: (0, 0)),
-        ],
+        in_specs=[row, row, row,
+                  pl.BlockSpec((1, n_targets), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((1, n_targets), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, n_targets), jnp.float32),
+        out_shape=out_struct((1, n_targets), jnp.float32, values,
+                             cumw_prev, cumw, targets),
         interpret=interpret,
-    )(values.reshape(n, block), cumw_prev.reshape(n, block),
-      cumw.reshape(n, block), targets.reshape(1, n_targets))[0]
+    )(values.reshape(n, 1, block), cumw_prev.reshape(n, 1, block),
+      cumw.reshape(n, 1, block), targets.reshape(1, n_targets))[0]

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import out_struct, row_spec
+
 # Item tile: (8, 128) f32 = one native VREG tile per load; 4 tiles deep to
 # amortize grid overhead → 4096 items per grid step, 16 KiB of values in VMEM.
 _BLOCK_ITEMS = 4096
@@ -42,10 +44,12 @@ def _kernel(values_ref, strata_ref, mask_ref, out_ref, *, num_strata: int):
     onehot = jnp.where(s[:, None] == cols, m[:, None], 0.0)
 
     feats = jnp.stack([m, v * m, v * v * m], axis=-1)     # f32[B, 3]
-    # [X, B] @ [B, 3] on the MXU.
+    # [X, B] @ [B, 3] on the MXU, at f32 precision: the default TPU
+    # contraction may round the summed values to bf16.
     tile = jax.lax.dot_general(
-        onehot, feats, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+        onehot, feats, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     out_ref[...] += tile
 
 
@@ -67,19 +71,15 @@ def stratified_stats(
         strata = jnp.pad(strata, (0, pad))
         mask = jnp.pad(mask, (0, pad))
     n = values.shape[0] // block
-    v2 = values.reshape(n, block)
-    s2 = strata.reshape(n, block)
-    k2 = mask.reshape(n, block)
+    row = row_spec(block)
 
     return pl.pallas_call(
         functools.partial(_kernel, num_strata=num_strata),
         grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-        ],
+        in_specs=[row, row, row],
         out_specs=pl.BlockSpec((num_strata, 3), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_strata, 3), jnp.float32),
+        out_shape=out_struct((num_strata, 3), jnp.float32, values, strata,
+                             mask),
         interpret=interpret,
-    )(v2, s2, k2)
+    )(values.reshape(n, 1, block), strata.reshape(n, 1, block),
+      mask.reshape(n, 1, block))

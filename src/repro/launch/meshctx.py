@@ -33,6 +33,14 @@ def current_mesh() -> Mesh | None:
 
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh):
+    # The constraints below are Auto-mode sharding; ``jax.make_mesh``
+    # builds Explicit axes, whose arrays carry sharding in their types
+    # and refuse ``with_sharding_constraint``. Install the same devices
+    # and axes as Auto.
+    auto = jax.sharding.AxisType.Auto
+    if any(t != auto for t in mesh.axis_types):
+        mesh = Mesh(mesh.devices, mesh.axis_names,
+                    axis_types=(auto,) * len(mesh.axis_names))
     prev = getattr(_state, "mesh", None)
     _state.mesh = mesh
     try:

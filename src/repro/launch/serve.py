@@ -448,4 +448,7 @@ def _qslice(pipe, name: str) -> tuple[int, int]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

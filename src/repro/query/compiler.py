@@ -126,8 +126,12 @@ class CompiledQueryPlan:
                 a, b, st2 = q.estimate[None], q.bound(2.0)[None], ()
             elif sp.kind == "count":
                 # HT count is exact per stratum given the metadata
-                # (every item's indicator is 1): variance 0.
-                a = jnp.sum(y * res.meta.weight)[None]
+                # (every item's indicator is 1): variance 0. It is the
+                # integer number of items the sources sent, carried up
+                # the tree by weights that divide; TPU f32 division is
+                # not correctly rounded and leaves a few ulps, which
+                # rounding to the integer removes.
+                a = jnp.round(jnp.sum(y * res.meta.weight))[None]
                 b, st2 = jnp.zeros((1,), jnp.float32), ()
             elif sp.kind == "mean":
                 q = err.approx_mean_from_moments(y, s1, s2, res.meta)

@@ -211,8 +211,20 @@ def _fold_level(key: jax.Array, lvl_v: jnp.ndarray, lvl_w: jnp.ndarray,
     cumw = jnp.cumsum(w_s)
     total = cumw[-1]
     n_live = jnp.sum(w_s > 0.0)
-    zero_carry = jnp.zeros((m_up,), jnp.float32)
-    zero = jnp.zeros((), jnp.float32)
+    # Under shard_map both cond branches must return the same varying-
+    # manual-axes type. Every data-derived output varies over the axes of
+    # (v, w, key), so the branches' constant outputs are cast to that set
+    # here, before the cond (a no-op outside shard_map, where it is empty).
+    vma = jax.typeof(v_s).vma | jax.typeof(w_s).vma | jax.typeof(key).vma
+
+    def varying(x):
+        axes = tuple(sorted(vma - jax.typeof(x).vma))
+        return jax.lax.pcast(x, axes, to="varying") if axes else x
+
+    zero_carry = varying(jnp.zeros((m_up,), jnp.float32))
+    zero_cap = varying(jnp.zeros((cap,), jnp.float32))
+    zero = varying(jnp.zeros((), jnp.float32))
+    one = varying(jnp.ones((), jnp.float32))
 
     def exact():
         # Everything fits: pack live slots to the front (stable, so the
@@ -233,11 +245,9 @@ def _fold_level(key: jax.Array, lvl_v: jnp.ndarray, lvl_w: jnp.ndarray,
         vmax = jnp.max(jnp.where(w_s > 0.0, v_s, -jnp.inf))
         picked = jnp.where(t >= total, vmax, picked)
         pw = jnp.full((m,), q, jnp.float32)
-        one = jnp.ones((), jnp.float32)
         if m_up == 0:
             return (picked, pw, zero_carry, zero_carry, one, q * q)
-        return (jnp.zeros((cap,), jnp.float32),
-                jnp.zeros((cap,), jnp.float32), picked, pw, one, q * q)
+        return (zero_cap, zero_cap, picked, pw, one, q * q)
 
     return jax.lax.cond(n_live <= cap, exact, compact)
 

@@ -224,6 +224,11 @@ class StreamingExecutor:
         # flush empty root windows (ok=False, no published rows) —
         # reusing the one warm jitted program instead of compiling a
         # fresh one per drain length.
+        # This swap zeroes the staging set the previous epoch reads:
+        # ``run_epoch`` may still read host memory after it returns (on
+        # the CPU JAX aliases numpy arrays), so that epoch must be done.
+        if self._pending is not None:
+            jax.block_until_ready(self._pending.wa)
         staged = self._staging.swap()
         self._state = self._monitor.fold_into(self._state)
         key = jax.random.fold_in(self._pipeline.default_key, self._epoch)

@@ -92,9 +92,9 @@ class DoubleBuffer:
     # ------------------------------------------------------------ swap --
     def swap(self) -> StagedEpoch:
         """Hand the active (filled) set over and activate the other one,
-        zeroed for reuse. The returned arrays stay valid until the swap
-        after next — ``run_epoch`` copies them host→device at dispatch,
-        so that lifetime is enough by construction."""
+        zeroed for reuse. The returned arrays stay valid until the next
+        swap, which zeroes them: the caller waits for the epoch that
+        reads them before swapping again."""
         buf = self._bufs[self._active]
         out = StagedEpoch(buf["values"], buf["strata"], buf["counts"],
                           buf["offered"], buf["first_arrival"])

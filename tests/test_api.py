@@ -369,7 +369,6 @@ def test_compile_with_mesh_matches_spmd_epoch():
     """compile(spec, mesh=...) ≡ direct per-interval
     spmd_local_then_root calls on a 1-device mesh, bit-for-bit."""
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.tree import spmd_local_then_root
@@ -391,11 +390,11 @@ def test_compile_with_mesh_matches_spmd_epoch():
 
     spec1 = IntervalBatch(P("data"), P("data"), P("data"),
                           StratumMeta(P(), P()))
-    one = shard_map(
+    one = jax.jit(jax.shard_map(
         lambda k, b: spmd_local_then_root(
             k, b, axis_name="data", num_strata=X, local_budget=32,
             root_budget=64, allocation="fair", sampler_backend="topk"),
-        mesh=mesh, in_specs=(P(), spec1), out_specs=(P(), P()))
+        mesh=mesh, in_specs=(P(), spec1), out_specs=(P(), P())))
     for i in range(ticks):
         b = IntervalBatch(batches.value[i], batches.stratum[i],
                           batches.valid[i],

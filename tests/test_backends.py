@@ -153,13 +153,13 @@ def test_get_backend_unknown_name():
         sampling.get_backend("quantum")
 
 
-@pytest.mark.parametrize("backend,check_rep", [
-    ("topk", True), ("pallas", False),  # pallas_call has no replication rule
+@pytest.mark.parametrize("backend,check_vma", [
+    # the Pallas interpreter mis-types its block slices under check_vma
+    ("topk", True), ("pallas", False),
 ])
-def test_spmd_path_backend_selectable(backend, check_rep):
+def test_spmd_path_backend_selectable(backend, check_vma):
     """sampler_backend is honored end-to-end through the shard_map data
     plane (1-device mesh)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.tree import spmd_local_then_root
@@ -177,11 +177,11 @@ def test_spmd_path_backend_selectable(backend, check_rep):
                                     sampler_backend=backend)
         return s.estimate
 
-    fn = shard_map(f, mesh=mesh,
-                   in_specs=(P(), IntervalBatch(P("data"), P("data"),
-                                                P("data"),
-                                                StratumMeta(P(), P()))),
-                   out_specs=P(), check_rep=check_rep)
+    fn = jax.shard_map(f, mesh=mesh,
+                       in_specs=(P(), IntervalBatch(P("data"), P("data"),
+                                                    P("data"),
+                                                    StratumMeta(P(), P()))),
+                       out_specs=P(), check_vma=check_vma)
     est = float(fn(jax.random.PRNGKey(0), batch))
     exact = float(np.asarray(batch.value).sum())
     assert abs(est - exact) / exact < 0.1

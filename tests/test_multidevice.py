@@ -43,12 +43,8 @@ _SPMD_SCRIPT = textwrap.dedent("""
                                      local_budget=256, root_budget=512)
         return s.estimate, s.variance, mn.estimate
     specs = IntervalBatch(P("data"), P("data"), P("data"), StratumMeta(P(), P()))
-    try:
-        shard_map = jax.shard_map            # jax >= 0.6
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-    fn = shard_map(f, mesh=mesh, in_specs=(P(), specs),
-                   out_specs=(P(), P(), P()))
+    fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P(), specs),
+                               out_specs=(P(), P(), P())))
     est, var, mean = fn(jax.random.PRNGKey(0), batch)
     print(json.dumps({
         "est": float(est), "var": float(var), "mean": float(mean),

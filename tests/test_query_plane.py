@@ -9,7 +9,7 @@ import pytest
 
 from repro.core import whs
 from repro.core.tree import HostTree
-from repro.core.types import IntervalBatch, StratumMeta
+from repro.core.types import IntervalBatch, SampleResult, StratumMeta
 from repro.core.window import TreeState
 from repro.query import sketches as sk
 from repro.query.registry import QueryRegistry, QuerySpec
@@ -138,6 +138,29 @@ def test_fraction_one_quantile_and_hh_exact():
     true = {k: (np.round(vals).astype(np.int64) == k).sum() for k in keys}
     for k, e in zip(keys, ests):
         assert e == true[k], (k, e, true[k])
+
+
+def test_count_is_the_integer_item_count_despite_weight_rounding():
+    """13 of 1000 items sampled carry the f32 weight 1000/13, and
+    13 · fl(1000/13) is not 1000 in f32; the HT count answers the 1000
+    items exactly, with bound 0 (TPU division, which is not correctly
+    rounded, leaves such residues on every window)."""
+    c, n = 1000, 13
+    w = np.float32(c) / np.float32(n)
+    assert np.float32(w * np.float32(n)) != np.float32(c)
+    batch = IntervalBatch(jnp.ones((n,), jnp.float32),
+                          jnp.zeros((n,), jnp.int32), jnp.ones((n,), bool),
+                          StratumMeta.identity(1))
+    meta = StratumMeta(jnp.asarray([w], jnp.float32),
+                       jnp.asarray([n], jnp.float32))
+    res = SampleResult(batch.valid, meta, jnp.asarray([c], jnp.float32),
+                           jnp.asarray([n], jnp.float32),
+                           jnp.asarray([n], jnp.float32))
+    plan = QueryRegistry().register_count("n").compile(1)
+    _, ans, bnd = plan.evaluate(jax.random.PRNGKey(0), batch, res,
+                                plan.init_state())
+    assert float(plan.answer(ans, "n")[0]) == float(c)
+    assert float(plan.answer(bnd, "n")[0]) == 0.0
 
 
 # ----------------------------------------------------------- scan engine --

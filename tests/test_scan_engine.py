@@ -160,10 +160,6 @@ def test_spmd_epoch_matches_per_interval():
     calls with fold_in(key, i) keys, bit-for-bit (1-device mesh)."""
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
     from repro.core.tree import (spmd_local_then_root,
                                  spmd_local_then_root_epoch)
     from repro.core.types import IntervalBatch, StratumMeta
@@ -182,14 +178,16 @@ def test_spmd_epoch_matches_per_interval():
 
     specs_t = IntervalBatch(P(None, "data"), P(None, "data"),
                             P(None, "data"), StratumMeta(P(), P()))
-    s_t, m_t = shard_map(
+    s_t, m_t = jax.jit(jax.shard_map(
         lambda k, b: spmd_local_then_root_epoch(k, b, **kw),
-        mesh=mesh, in_specs=(P(), specs_t), out_specs=(P(), P()))(key, batches)
+        mesh=mesh, in_specs=(P(), specs_t),
+        out_specs=(P(), P())))(key, batches)
 
     spec1 = IntervalBatch(P("data"), P("data"), P("data"),
                           StratumMeta(P(), P()))
-    one = shard_map(lambda k, b: spmd_local_then_root(k, b, **kw),
-                    mesh=mesh, in_specs=(P(), spec1), out_specs=(P(), P()))
+    one = jax.jit(jax.shard_map(lambda k, b: spmd_local_then_root(k, b, **kw),
+                                mesh=mesh, in_specs=(P(), spec1),
+                                out_specs=(P(), P())))
     for i in range(ticks):
         b = IntervalBatch(batches.value[i], batches.stratum[i],
                           batches.valid[i],

@@ -541,11 +541,6 @@ def test_spmd_clt_coverage_vmapped():
     from repro.core import tree as T
     from repro.core.types import IntervalBatch, StratumMeta
 
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-
     pipe = api.compile(_tenant_spec(fraction=0.125), mesh=_mesh1())
     plan = pipe.plan
     batches = _batches(1, m=2048, seed=7)
@@ -568,8 +563,8 @@ def test_spmd_clt_coverage_vmapped():
 
     item = P("data")
     specs = IntervalBatch(item, item, item, StratumMeta(P(), P()))
-    fn = shard_map(many, mesh=_mesh1(), in_specs=(P(), specs),
-                   out_specs=(P(), P()))
+    fn = jax.shard_map(many, mesh=_mesh1(), in_specs=(P(), specs),
+                       out_specs=(P(), P()))
     keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(n_draws))
     ans, bnd = jax.jit(fn)(keys, batch)
     ans, bnd = np.asarray(ans), np.asarray(bnd)

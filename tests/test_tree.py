@@ -59,7 +59,6 @@ def test_spmd_hierarchy_single_device():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.core.tree import spmd_local_then_root
     from repro.core.types import IntervalBatch, StratumMeta
@@ -81,9 +80,9 @@ def test_spmd_hierarchy_single_device():
 
     batch_specs = IntervalBatch(P("data"), P("data"), P("data"),
                                 StratumMeta(P(), P()))
-    fn = shard_map(f, mesh=mesh,
-                   in_specs=(P(), batch_specs),
-                   out_specs=(P(), P(), P()))
+    fn = jax.shard_map(f, mesh=mesh,
+                       in_specs=(P(), batch_specs),
+                       out_specs=(P(), P(), P()))
     est, var, mean = fn(jax.random.PRNGKey(0), batch)
     exact = float(np.asarray(batch.value).sum())
     assert abs(float(est) - exact) / exact < 0.1
