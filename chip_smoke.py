@@ -387,14 +387,9 @@ def main_path_phase(*, seed: int, rate: int = RATE, epochs: int = EPOCHS,
     t = time.perf_counter()
     ex.start(pipe, sources)            # pre-warm: compiles the epoch
     prewarm_s = time.perf_counter() - t
-    epoch_s = []
-    for _ in range(epochs):
-        t = time.perf_counter()
-        for _ in range(epoch_ticks):
-            log.append([])
-            ex.pump()
-        jax.block_until_ready(ex.state)
-        epoch_s.append(time.perf_counter() - t)
+    for _ in range(epochs * epoch_ticks):
+        log.append([])
+        ex.pump()
     stats = ex.stop()
     ticks_values = [np.concatenate(parts) if parts else
                     np.zeros(0, np.float32) for parts in log]
@@ -411,7 +406,6 @@ def main_path_phase(*, seed: int, rate: int = RATE, epochs: int = EPOCHS,
                            epoch_ticks)
     return {"capacity": cap, "lower_check_s": lower_s,
             "prewarm_compile_s": prewarm_s,
-            "epoch_wall_s": epoch_s,
             "items_per_epoch": [int(sum(v.size for v in ticks_values[
                 e * epoch_ticks:(e + 1) * epoch_ticks]))
                 for e in range(epochs)],

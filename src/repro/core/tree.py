@@ -506,11 +506,14 @@ def _build_scan_tick(fanin, capacities, sample_sizes, interval_ticks,
         # Source → level-0 delivery (one slice of the epoch's ingest batch).
         # With a 1-tick level-0 interval the buffer is empty here (it
         # flushed last tick), so the append is a scatter-free overwrite.
-        (lv["values"][0], lv["strata"][0], lv["fill"][0],
-         lv["dropped"][0]) = _append_rows(
-            lv["values"][0], lv["strata"][0], lv["fill"][0],
-            lv["dropped"][0], ing_v, ing_s, ing_n,
-            empty=int(interval_ticks[0]) == 1)
+        # The named scopes (``ingest``, ``level{l}``) only tag the ops'
+        # metadata, so a device trace can charge time to a tree level.
+        with jax.named_scope("ingest"):
+            (lv["values"][0], lv["strata"][0], lv["fill"][0],
+             lv["dropped"][0]) = _append_rows(
+                lv["values"][0], lv["strata"][0], lv["fill"][0],
+                lv["dropped"][0], ing_v, ing_s, ing_n,
+                empty=int(interval_ticks[0]) == 1)
 
         n_fwd_levels = []
         root_out = None
@@ -614,13 +617,14 @@ def _build_scan_tick(fanin, capacities, sample_sizes, interval_ticks,
                        lv["seen"][l + 1], jnp.zeros((), jnp.int32))
                 return nul + keep
 
-            if iv == 1:
-                out = run_level()
-            else:
-                # cond executes ONE branch at runtime: a level whose
-                # interval has not elapsed costs nothing — its buffers
-                # keep accumulating untouched.
-                out = jax.lax.cond(t % iv == 0, run_level, skip_level)
+            with jax.named_scope(f"level{l}"):
+                if iv == 1:
+                    out = run_level()
+                else:
+                    # cond executes ONE branch at runtime: a level whose
+                    # interval has not elapsed costs nothing — its buffers
+                    # keep accumulating untouched.
+                    out = jax.lax.cond(t % iv == 0, run_level, skip_level)
 
             if is_root:
                 root_out, tail, q_out = out

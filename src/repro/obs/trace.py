@@ -3,31 +3,44 @@
 Context-manager spans record monotonic wall-time plus metadata into a
 bounded ring buffer. The canonical span names the drivers emit:
 
-* ``ingest``            — host-side epoch batch staging
-* ``epoch_dispatch``    — the jitted epoch call (async dispatch)
-* ``block_until_ready`` — device→host sync on the epoch outputs
+* ``ingest``            — host-side epoch batch staging (CLIs)
+* ``epoch_dispatch``    — the jitted epoch call (async dispatch, CLIs)
+* ``block_until_ready`` — device→host sync on the epoch outputs (CLIs)
 * ``admit`` / ``retire``— tenant churn state edits
 * ``checkpoint``        — ``save_state`` / ``restore_state``
+* ``executor.*``        — the stages of ``serve.StreamingExecutor``,
+  one span per stage per pump: ``tick`` (one pump or drain tick),
+  ``enqueue``, ``dequeue``, ``stage``, ``account``; at an epoch edge
+  ``flush``, ``wait_device``, ``swap``, ``dispatch``, ``collect``; and
+  ``drain`` around ``stop()``'s source-less ticks
+
+Counters (:meth:`SpanTracer.count`) are snake_case names, exposed as
+``repro_<name>_total``; the executor bumps ``executor_h2d_bytes`` and
+``executor_collect_waited``.
 
 Export with :meth:`SpanTracer.chrome_trace` / :meth:`SpanTracer.save`:
 the JSON loads directly in ``chrome://tracing`` and
-https://ui.perfetto.dev. Spans also open a ``jax.profiler``
-``TraceAnnotation`` when available, so they line up with device traces
-captured via ``jax.profiler.trace``.
+https://ui.perfetto.dev. Each span also opens a ``jax.profiler``
+``TraceAnnotation`` carrying its scalar metadata, so a profiler trace
+(``jax.profiler.trace``) holds every span on the device's clock, nested
+under whatever annotation encloses the call.
 
 A module-global default tracer (:func:`get_tracer`) keeps the call
 sites one-liners — ``with obs.span("epoch_dispatch"): ...`` — and a
 disabled tracer's span is a no-op (one truthiness check), so
-instrumented hot paths cost nothing when tracing is off.
+instrumented hot paths cost nothing when tracing is off. A span yields
+its metadata dict: keys set inside the block (a count known only at
+the end) are recorded too.
 """
 from __future__ import annotations
 
 import collections
 import json
+import re
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, NamedTuple
+from typing import NamedTuple
 
 try:  # optional: line spans up with device profiles when jax is importable
     from jax.profiler import TraceAnnotation as _TraceAnnotation
@@ -42,10 +55,20 @@ class Span(NamedTuple):
     depth: int         # nesting depth at open time (0 = top level)
     tid: int
     meta: dict
+    parent: str | None = None   # enclosing span on the same thread
+
+
+_COUNTER_NAME = re.compile(r"[a-z_][a-z0-9_]*\Z")
+
+
+def _scalars(items) -> dict:
+    """The metadata a ``TraceAnnotation`` can carry: scalar values."""
+    return {k: v for k, v in items if isinstance(v, (bool, int, float, str))}
 
 
 class SpanTracer:
-    """Bounded ring buffer of :class:`Span` records + per-name totals."""
+    """Bounded ring buffer of :class:`Span` records + per-name totals.
+    Nesting is tracked per thread; each span records its parent."""
 
     def __init__(self, capacity: int = 4096, enabled: bool = True):
         self.capacity = int(capacity)
@@ -54,34 +77,52 @@ class SpanTracer:
         self.durations: dict[str, float] = collections.defaultdict(float)
         self.calls: collections.Counter = collections.Counter()
         self.counters: collections.Counter = collections.Counter()
-        self._stack: list[str] = []
+        self._local = threading.local()
+
+    def _stack(self) -> list[str]:
+        """This thread's open spans, outermost first."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
     @contextmanager
     def span(self, name: str, **meta):
+        """``with tracer.span("executor.stage", items=n) as meta: ...``;
+        ``meta`` is the span's metadata, open to keys set in the block."""
         if not self.enabled:
-            yield
+            yield {}
             return
-        ann = _TraceAnnotation(name) if _TraceAnnotation is not None else None
-        depth = len(self._stack)
-        self._stack.append(name)
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        n_open = len(meta)
+        ann = (_TraceAnnotation(name, **_scalars(meta.items()))
+               if _TraceAnnotation is not None else None)
+        stack.append(name)
         if ann is not None:
             ann.__enter__()
         t0 = time.perf_counter()
         try:
-            yield
+            yield meta
         finally:
             t1 = time.perf_counter()
             if ann is not None:
+                if len(meta) > n_open:      # keys set inside the block
+                    ann.set_metadata(
+                        **_scalars(list(meta.items())[n_open:]))
                 ann.__exit__(None, None, None)
-            self._stack.pop()
-            self.events.append(Span(name, t0, t1, depth,
-                                    threading.get_ident(), meta))
+            stack.pop()
+            self.events.append(Span(name, t0, t1, len(stack),
+                                    threading.get_ident(), meta, parent))
             self.durations[name] += t1 - t0
             self.calls[name] += 1
 
     def count(self, name: str, n: int = 1) -> None:
-        """Bump a named counter (exposed by the metrics layer)."""
+        """Bump a named counter (exposed by the metrics layer as
+        ``repro_<name>_total``, so ``name`` is snake_case)."""
         if self.enabled:
+            if not _COUNTER_NAME.match(name):
+                raise ValueError(f"counter name {name!r} is not snake_case")
             self.counters[name] += n
 
     def clear(self) -> None:
@@ -89,7 +130,7 @@ class SpanTracer:
         self.durations.clear()
         self.calls.clear()
         self.counters.clear()
-        self._stack.clear()
+        self._local = threading.local()
 
     # ------------------------------------------------------------ export --
     def chrome_trace(self) -> dict:
@@ -99,7 +140,7 @@ class SpanTracer:
             "name": ev.name, "ph": "X", "cat": "repro",
             "ts": ev.t0 * 1e6, "dur": (ev.t1 - ev.t0) * 1e6,
             "pid": 0, "tid": ev.tid,
-            "args": {**ev.meta, "depth": ev.depth},
+            "args": {**ev.meta, "depth": ev.depth, "parent": ev.parent},
         } for ev in self.events]
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 

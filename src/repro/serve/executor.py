@@ -29,6 +29,20 @@ lifecycle (init → subscribe → pump → stop):
   a final short epoch flushes the staged remainder, the last dispatch
   collects. After ``stop()`` no queue holds items — pinned in tests.
 
+Tracing: each stage of a pump is one ``executor.*`` span on the
+process's default tracer (``obs.trace.get_tracer``), never
+one per item — ``tick`` around the pump, inside it ``enqueue``
+(sources → queues), ``dequeue`` (``get_many``), ``stage``, ``account``
+(straggler scores, rate EWMA, Eq. 9 α), and at an epoch edge ``flush``
+around ``wait_device`` (the previous epoch), ``swap``, ``dispatch``
+(``run_epoch`` with its host-to-device copy) and ``collect``
+(``rows()`` and publication); ``drain`` wraps ``stop()``'s source-less
+ticks. ``dispatch`` and ``collect`` carry the epoch index. Counters:
+``executor_h2d_bytes`` (staged bytes handed to ``run_epoch``) and
+``executor_collect_waited`` (collections that found the device still
+running). Spans never touch the data: windows are bitwise the same
+with the tracer on or off.
+
 Determinism: the epoch PRNG key is ``fold_in(pipeline.default_key,
 epoch_index)``, sources are passive between pumps, and the clock is
 injectable — a fake clock plus deterministic sources reproduces a run
@@ -43,6 +57,7 @@ import jax
 import numpy as np
 
 from repro.obs.telemetry import StragglerMonitor
+from repro.obs.trace import get_tracer
 from repro.serve.queues import POLICIES, BoundedShardQueue
 from repro.serve.staging import DoubleBuffer
 from repro.serve.windows import PublishedWindow, WindowPublisher
@@ -54,6 +69,7 @@ class _Pending(NamedTuple):
     wa: Any              # WindowAnswers (device arrays, possibly in flight)
     base_tick: int       # global tick of the epoch's first row
     dispatched: float
+    epoch: int           # the executor's epoch index
 
 
 class StreamingExecutor:
@@ -150,23 +166,53 @@ class StreamingExecutor:
     def _tick(self, *, drain: bool) -> list[PublishedWindow]:
         if not self._running:
             raise RuntimeError("executor is not started")
+        tracer = get_tracer()
+        span = tracer.span
         n0 = len(self.published)
-        t_start = self.clock()
-        device_busy = (self._pending is not None
-                       and not _is_ready(self._pending.wa))
-        drops0 = sum(q.items_dropped for q in self._queues)
-        trunc0 = self._staging.truncated_total
-        if not drain:
-            for src in self._sources:
-                src.pump(t_start)
-        arrived = np.zeros(self._n_shards, np.int64)
-        for shard, q in enumerate(self._queues):
-            values, strata, arrivals = q.get_many(self.max_records)
-            arrived[shard] = values.size
-            if values.size:
-                self._last_delivery[shard] = t_start
-                self._staging.stage(self._t, shard, values, strata,
-                                    arrival=float(arrivals.min()))
+        with span("executor.tick", tick=self._global_tick, drain=drain):
+            t_start = self.clock()
+            device_busy = (self._pending is not None
+                           and not _is_ready(self._pending.wa))
+            drops0 = sum(q.items_dropped for q in self._queues)
+            trunc0 = self._staging.truncated_total
+            if not drain:
+                with span("executor.enqueue") as meta:
+                    in0 = self._items_in() if tracer.enabled else 0
+                    for src in self._sources:
+                        src.pump(t_start)
+                    if tracer.enabled:
+                        meta["items"] = self._items_in() - in0
+            with span("executor.dequeue") as meta:
+                drained = [q.get_many(self.max_records)
+                           for q in self._queues]
+                arrived = np.asarray([v.size for v, _, _ in drained],
+                                     np.int64)
+                meta["items"] = n_items = int(arrived.sum())
+            with span("executor.stage", items=n_items):
+                for shard, (values, strata, arrivals) in enumerate(drained):
+                    if values.size:
+                        self._last_delivery[shard] = t_start
+                        self._staging.stage(self._t, shard, values, strata,
+                                            arrival=float(arrivals.min()))
+            del drained
+            with span("executor.account"):
+                self._account(drain, arrived, drops0, trunc0)
+            self._t += 1
+            self._global_tick += 1
+            if self._t == self.epoch_ticks:
+                self._flush(self.epoch_ticks)
+            dt = self.clock() - t_start
+            self._ingest_seconds += dt
+            if device_busy:
+                self._overlap_seconds += dt
+        return self.published[n0:]
+
+    def _items_in(self) -> int:
+        return sum(q.items_in for q in self._queues)
+
+    def _account(self, drain: bool, arrived, drops0: int, trunc0: int):
+        """Straggler scores, the per-shard rate EWMA and the tick's Eq. 9
+        accounting (``_tick_alpha``), after the tick's items staged."""
         now = self.clock()
         shed = ((sum(q.items_dropped for q in self._queues) - drops0)
                 + (self._staging.truncated_total - trunc0))
@@ -183,15 +229,6 @@ class StreamingExecutor:
         self._rate = np.where(fresh, arrived, self._rate)
         self._meta[self._global_tick] = self._tick_alpha(
             arrived, present, shed)
-        self._t += 1
-        self._global_tick += 1
-        if self._t == self.epoch_ticks:
-            self._flush(self.epoch_ticks)
-        dt = self.clock() - t_start
-        self._ingest_seconds += dt
-        if device_busy:
-            self._overlap_seconds += dt
-        return self.published[n0:]
 
     def _tick_alpha(self, arrived, present, shed: int) -> dict:
         """Eq. 9 arrived-weight accounting for one tick: on-time shards
@@ -227,42 +264,64 @@ class StreamingExecutor:
         # This swap zeroes the staging set the previous epoch reads:
         # ``run_epoch`` may still read host memory after it returns (on
         # the CPU JAX aliases numpy arrays), so that epoch must be done.
-        if self._pending is not None:
-            jax.block_until_ready(self._pending.wa)
-        staged = self._staging.swap()
-        self._state = self._monitor.fold_into(self._state)
-        key = jax.random.fold_in(self._pipeline.default_key, self._epoch)
-        self._state, wa = self._pipeline.run_epoch(
-            self._state, key, staged.values, staged.strata, staged.counts,
-            self._budgets)
-        prev, self._pending = self._pending, _Pending(
-            wa=wa, base_tick=self._global_tick - n_ticks,
-            dispatched=self.clock())
-        if prev is not None:
-            self._collect(prev)
-        self._epoch += 1
-        self._t = 0
-        # Padded empty ticks advanced the pipeline's tick counter past
-        # the pump count; follow it so later rows keep matching metas.
-        self._global_tick += self.epoch_ticks - n_ticks
+        tracer = get_tracer()
+        span = tracer.span
+        with span("executor.flush", epoch=self._epoch):
+            waited = False
+            if self._pending is not None:
+                waited = tracer.enabled and not _is_ready(self._pending.wa)
+                with span("executor.wait_device",
+                          epoch=self._pending.epoch, waited=waited):
+                    jax.block_until_ready(self._pending.wa)
+            with span("executor.swap"):
+                staged = self._staging.swap()
+            nbytes = (staged.values.nbytes + staged.strata.nbytes
+                      + staged.counts.nbytes) if tracer.enabled else 0
+            with span("executor.dispatch", epoch=self._epoch, bytes=nbytes):
+                self._state = self._monitor.fold_into(self._state)
+                key = jax.random.fold_in(self._pipeline.default_key,
+                                         self._epoch)
+                self._state, wa = self._pipeline.run_epoch(
+                    self._state, key, staged.values, staged.strata,
+                    staged.counts, self._budgets)
+            tracer.count("executor_h2d_bytes", nbytes)
+            prev, self._pending = self._pending, _Pending(
+                wa=wa, base_tick=self._global_tick - n_ticks,
+                dispatched=self.clock(), epoch=self._epoch)
+            if prev is not None:
+                self._collect(prev, waited)
+            self._epoch += 1
+            self._t = 0
+            # Padded empty ticks advanced the pipeline's tick counter past
+            # the pump count; follow it so later rows keep matching metas.
+            self._global_tick += self.epoch_ticks - n_ticks
 
-    def _collect(self, pending: _Pending):
-        rows = self._pipeline.rows(pending.wa)   # blocks until ready
-        now = self.clock()
-        for row in rows:
-            tick = int(row["tick"])
-            metas = [self._meta.pop(t) for t in
-                     range(self._last_published_tick + 1, tick + 1)
-                     if t in self._meta]
-            kept = sum(m["kept"] for m in metas)
-            total = sum(m["total"] for m in metas)
-            alpha = kept / total if total > 0.0 else 1.0
-            first_arrival = min((m["first_arrival"] for m in metas),
-                                default=np.inf)
-            self.published.append(self._publisher.publish(
-                row, alpha=alpha, partial=alpha < 1.0 - 1e-9,
-                publish_time=now, first_arrival=first_arrival))
-            self._last_published_tick = tick
+    def _collect(self, pending: _Pending, waited: bool):
+        """Publish a dispatched epoch's windows. ``waited``: the device
+        was still running that epoch when the host came for it (known
+        only to an enabled tracer)."""
+        tracer = get_tracer()
+        if waited:
+            tracer.count("executor_collect_waited")
+        with tracer.span("executor.collect", epoch=pending.epoch,
+                         waited=waited) as meta:
+            rows = self._pipeline.rows(pending.wa)   # blocks until ready
+            meta["windows"] = len(rows)
+            now = self.clock()
+            for row in rows:
+                tick = int(row["tick"])
+                metas = [self._meta.pop(t) for t in
+                         range(self._last_published_tick + 1, tick + 1)
+                         if t in self._meta]
+                kept = sum(m["kept"] for m in metas)
+                total = sum(m["total"] for m in metas)
+                alpha = kept / total if total > 0.0 else 1.0
+                first_arrival = min((m["first_arrival"] for m in metas),
+                                    default=np.inf)
+                self.published.append(self._publisher.publish(
+                    row, alpha=alpha, partial=alpha < 1.0 - 1e-9,
+                    publish_time=now, first_arrival=first_arrival))
+                self._last_published_tick = tick
 
     # ------------------------------------------------------------ stop --
     def stop(self) -> dict:
@@ -276,17 +335,20 @@ class StreamingExecutor:
         # trips on a bookkeeping bug.
         limit = 2 * (self.queue_capacity // max(self.max_records, 1)
                      + self.epoch_ticks + 2)
-        for _ in range(limit):
-            if not any(q.depth for q in self._queues):
-                break
-            self._tick(drain=True)
-        else:
-            raise RuntimeError("drain did not converge — queue depths "
-                               f"{[q.depth for q in self._queues]}")
+        tracer = get_tracer()
+        with tracer.span("executor.drain"):
+            for _ in range(limit):
+                if not any(q.depth for q in self._queues):
+                    break
+                self._tick(drain=True)
+            else:
+                raise RuntimeError("drain did not converge — queue depths "
+                                   f"{[q.depth for q in self._queues]}")
         if self._t > 0:
             self._flush(self._t)
         if self._pending is not None:
-            self._collect(self._pending)
+            self._collect(self._pending, tracer.enabled
+                          and not _is_ready(self._pending.wa))
             self._pending = None
         self._running = False
         return self.stats()

@@ -315,9 +315,95 @@ def test_chrome_trace_schema(tmp_path):
 
 def test_disabled_tracer_records_nothing():
     tr = SpanTracer(enabled=False)
-    with tr.span("x"):
-        pass
-    assert not tr.events and not tr.calls
+    with tr.span("x") as meta:
+        meta["items"] = 3
+    tr.count("x_total")
+    assert not tr.events and not tr.calls and not tr.counters
+
+
+def test_span_nesting_is_per_thread():
+    import threading
+
+    tr = SpanTracer()
+    opened = threading.Barrier(2)
+
+    def work(tag):
+        with tr.span(f"outer.{tag}"):
+            opened.wait()        # both threads hold an open span here
+            with tr.span(f"inner.{tag}"):
+                opened.wait()
+
+    threads = [threading.Thread(target=work, args=(t,)) for t in "ab"]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    by_name = {ev.name: ev for ev in tr.events}
+    for tag in "ab":
+        inner, outer = by_name[f"inner.{tag}"], by_name[f"outer.{tag}"]
+        assert (inner.depth, inner.parent) == (1, f"outer.{tag}")
+        assert (outer.depth, outer.parent) == (0, None)
+        assert inner.tid == outer.tid
+    assert by_name["inner.a"].tid != by_name["inner.b"].tid
+    assert tr.well_formed()
+
+
+def test_chrome_trace_records_each_spans_parent():
+    tr = SpanTracer()
+    with tr.span("executor.tick", tick=1):
+        with tr.span("executor.flush", epoch=0):
+            with tr.span("executor.dispatch", epoch=0):
+                pass
+    args = {e["name"]: e["args"] for e in tr.chrome_trace()["traceEvents"]}
+    assert args["executor.tick"]["parent"] is None
+    assert args["executor.flush"]["parent"] == "executor.tick"
+    assert args["executor.dispatch"] == {"epoch": 0, "depth": 2,
+                                         "parent": "executor.flush"}
+
+
+def test_span_metadata_reaches_the_trace_annotation(monkeypatch):
+    from repro.obs import trace as trace_mod
+
+    made = []
+
+    class Recorder:
+        def __init__(self, name, **kwargs):
+            self.name, self.kwargs, self.late = name, kwargs, {}
+            made.append(self)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **kwargs):
+            self.late.update(kwargs)
+
+    monkeypatch.setattr(trace_mod, "_TraceAnnotation", Recorder)
+    tr = SpanTracer()
+    with tr.span("executor.collect", epoch=4, waited=True,
+                 rows=np.zeros(3)) as meta:
+        meta["windows"] = 8
+    (ann,) = made
+    # Scalars go to the annotation when it opens, keys set inside the
+    # block when it closes; arrays stay in the ring only.
+    assert ann.name == "executor.collect"
+    assert ann.kwargs == {"epoch": 4, "waited": True}
+    assert ann.late == {"windows": 8}
+    assert tr.events[0].meta["windows"] == 8
+
+
+def test_counter_round_trips_through_prometheus_text():
+    tr = SpanTracer()
+    tr.count("executor_h2d_bytes", 1024)
+    tr.count("executor_h2d_bytes", 512)
+    tr.count("executor_collect_waited")
+    fams = parse_prometheus_text(metrics_text(tracer=tr))
+    assert fams["repro_executor_h2d_bytes_total"]["samples"][()] == 1536
+    assert fams["repro_executor_collect_waited_total"]["samples"][()] == 1
+    with pytest.raises(ValueError, match="snake_case"):
+        tr.count("executor.h2d_bytes")
 
 
 # ---------------------------------------------------------------------------

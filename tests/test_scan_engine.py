@@ -230,3 +230,50 @@ def test_stream_source_batch_matches_ticks():
         assert counts[t] == len(v)
         np.testing.assert_array_equal(values[t, :len(v)], v)
         np.testing.assert_array_equal(strata[t, :len(v)], s)
+
+
+# ----------------------------------------------------------- named scopes --
+def test_tree_levels_are_named_scopes_that_change_metadata_only(
+        monkeypatch):
+    """The scan tick tags its ops ``ingest`` and ``level{l}`` (the root
+    is the last level) for a device trace; without the scopes the
+    compiled program is the same, metadata aside."""
+    import contextlib
+    import re
+
+    from repro import api
+
+    def compiled_text():
+        pipe = api.compile(api.PipelineSpec(
+            topology=api.TopologySpec(fanin=(4, 2, 1), capacity=256,
+                                      num_strata=X),
+            sampler=api.SamplerSpec(mode="whs", backend="topk",
+                                    fraction=0.25), seed=3))
+        b = jax.numpy.asarray(pipe.clamp_budgets(None), jax.numpy.float32)
+        args = (pipe.init(), pipe.default_key, b,
+                np.zeros((2, 4, 256), np.float32),
+                np.zeros((2, 4, 256), np.int32), np.zeros((2, 4), np.int32))
+        return pipe._epoch_fn(2).lower(*args).compile().as_text()
+
+    scoped = compiled_text()
+    names = set(re.findall(r'op_name="([^"]*)"', scoped))
+    for scope in ("ingest", "level0", "level1", "level2"):
+        assert any(f"/{scope}/" in n for n in names), scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    plain = compiled_text()
+    assert not any("/level0/" in n
+                   for n in re.findall(r'op_name="([^"]*)"', plain))
+
+    def strip(text):
+        # Drop op metadata, the source-location tables it points at, and
+        # instruction names (a call is named after a Python function the
+        # process may have traced before).
+        text = re.sub(r",? metadata=\{[^}]*\}", "", text)
+        text = re.sub(r"(?m)^(FileNames|FunctionNames|FileLocations|"
+                      r"StackFrames)\n(.+\n)*", "", text)
+        return re.sub(r"%[\w.\-]+", "%", text)
+
+    assert " fusion(" in strip(plain)
+    assert strip(scoped) == strip(plain)

@@ -12,7 +12,11 @@ The load-bearing laws (ISSUE 9):
   widened by 1/α ≥ 1 (partial bound ≥ full bound), and the late data
   folds into the next window (Σ raw counts conserves every item);
 * ``stop()`` drains: no queued items remain, accounting still closes;
-* the ``repro_serve_*`` metric families render and Prometheus-parse.
+* the ``repro_serve_*`` metric families render and Prometheus-parse;
+* the executor's ``executor.*`` spans: one per stage per pump, nested
+  as documented, epoch-tagged, in the profiler's trace under the
+  caller's annotation, and neutral — windows are bitwise the same with
+  the tracer off.
 
 All tests run with an injected fake clock and deterministic sources.
 """
@@ -333,6 +337,174 @@ def test_serve_metric_families_roundtrip():
         (("shard", "0"),)] == 0.0
     samples = fams["repro_serve_window_latency_seconds"]["samples"]
     assert (("quantile", "p50"),) in samples
+
+
+# -------------------------------------------------------------- spans --
+
+# Where each executor span opens: the span it nests directly inside.
+SPAN_PARENTS = {
+    "executor.tick": {None, "executor.drain"},
+    "executor.enqueue": {"executor.tick"},
+    "executor.dequeue": {"executor.tick"},
+    "executor.stage": {"executor.tick"},
+    "executor.account": {"executor.tick"},
+    "executor.flush": {"executor.tick", None},
+    "executor.wait_device": {"executor.flush"},
+    "executor.swap": {"executor.flush"},
+    "executor.dispatch": {"executor.flush"},
+    "executor.collect": {"executor.flush", None},
+    "executor.drain": {None},
+}
+
+
+def _two_epochs(monkeypatch, tracer, fraction=1.0, pump=None):
+    """Two 4-tick epochs and ``stop()`` on the small pipeline, with
+    ``tracer`` as the process's default; ``pump`` wraps each pump (a
+    profiler annotation in the trace test)."""
+    from repro.obs import trace as trace_mod
+
+    monkeypatch.setattr(trace_mod, "_GLOBAL", tracer)
+    pipe = api.compile(_spec(fraction))
+    clock = FakeClock()
+    ex = _executor(clock)
+    ex.start(pipe, [ConstantSource(0, rate=6, value=2.0, stratum=0),
+                    ConstantSource(1, rate=6, value=3.0, stratum=1)],
+             warmup=False)
+    for _ in range(8):
+        clock.advance(1.0)
+        if pump is None:
+            ex.pump()
+        else:
+            with pump():
+                ex.pump()
+    ex.stop()
+    return ex
+
+
+def test_executor_spans_name_and_nest_each_stage(monkeypatch):
+    from repro.obs.trace import SpanTracer
+
+    tr = SpanTracer()
+    _two_epochs(monkeypatch, tr)
+    assert {ev.name for ev in tr.events} == set(SPAN_PARENTS)
+    for ev in tr.events:
+        assert ev.parent in SPAN_PARENTS[ev.name], ev
+    assert tr.well_formed()
+    # One span per stage per pump, never per item or per shard.
+    for name in ("executor.tick", "executor.enqueue", "executor.dequeue",
+                 "executor.stage", "executor.account"):
+        assert tr.calls[name] == 8, name
+    assert tr.calls["executor.flush"] == tr.calls["executor.dispatch"] == 2
+    assert tr.calls["executor.swap"] == 2
+    assert tr.calls["executor.wait_device"] == 1
+    assert tr.calls["executor.collect"] == 2
+    assert tr.calls["executor.drain"] == 1
+    enq = [ev.meta["items"] for ev in tr.events
+           if ev.name == "executor.enqueue"]
+    assert enq == [12] * 8
+
+
+def test_dispatch_and_collect_carry_the_same_epoch(monkeypatch):
+    from repro.obs.trace import SpanTracer
+
+    tr = SpanTracer()
+    ex = _two_epochs(monkeypatch, tr)
+    dispatched = [ev.meta["epoch"] for ev in tr.events
+                  if ev.name == "executor.dispatch"]
+    collected = [ev for ev in tr.events if ev.name == "executor.collect"]
+    assert dispatched == [ev.meta["epoch"] for ev in collected] == [0, 1]
+    assert sum(ev.meta["windows"] for ev in collected) == len(ex.published)
+    assert all(isinstance(ev.meta["waited"], bool) for ev in collected)
+    # The flush that dispatches epoch 1 first waits for epoch 0.
+    assert [ev.meta["epoch"] for ev in tr.events
+            if ev.name == "executor.wait_device"] == [0]
+
+
+def test_executor_counters(monkeypatch):
+    from repro.obs.trace import SpanTracer
+
+    tr = SpanTracer()
+    _two_epochs(monkeypatch, tr)
+    # Per epoch: values f32[4,2,64], strata i32[4,2,64], counts i32[4,2].
+    assert tr.counters["executor_h2d_bytes"] == 2 * (4 * 2 * 64 * 8 + 32)
+    waited = [ev for ev in tr.events if ev.name == "executor.collect"
+              and ev.meta["waited"]]
+    assert tr.counters.get("executor_collect_waited", 0) == len(waited)
+    fams = parse_prometheus_text(metrics_text(tracer=tr))
+    assert fams["repro_executor_h2d_bytes_total"]["samples"][()] == \
+        tr.counters["executor_h2d_bytes"]
+    assert (("span", "executor.dispatch"),) in \
+        fams["repro_span_seconds_total"]["samples"]
+
+
+def test_a_disabled_tracer_records_nothing_from_the_executor(monkeypatch):
+    from repro.obs.trace import SpanTracer
+
+    tr = SpanTracer(enabled=False)
+    ex = _two_epochs(monkeypatch, tr)
+    assert len(ex.published) == 8
+    assert not tr.events and not tr.calls and not tr.counters
+
+
+def test_windows_are_bitwise_identical_with_the_tracer_on_and_off(monkeypatch):
+    from repro.obs.trace import SpanTracer
+
+    on = _two_epochs(monkeypatch, SpanTracer(), fraction=0.5).published
+    off = _two_epochs(monkeypatch, SpanTracer(enabled=False),
+                      fraction=0.5).published
+    assert len(on) == len(off) == 8
+    for a, b in zip(on, off):
+        assert a.tick == b.tick and a.partial == b.partial
+        np.testing.assert_array_equal(a.answers, b.answers)
+        np.testing.assert_array_equal(a.bounds, b.bounds)
+        assert a.sum == b.sum and a.mean == b.mean
+        assert a.n_sampled == b.n_sampled
+        np.testing.assert_array_equal(a.histogram, b.histogram)
+
+
+def test_a_profiler_trace_holds_every_executor_span_under_the_pump(
+        tmp_path, monkeypatch):
+    import glob
+
+    from repro.obs.trace import SpanTracer
+
+    tr = SpanTracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _two_epochs(monkeypatch, tr,
+                    pump=lambda: jax.profiler.TraceAnnotation("pump"))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+               dict(ev.stats))
+              for plane in jax.profiler.ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name == "pump" or ev.name.startswith("executor.")]
+    names = {e[0] for e in events}
+    assert names == set(SPAN_PARENTS) | {"pump"}
+    pumps = [e for e in events if e[0] == "pump"]
+    assert len(pumps) == 8
+    # Every span of a pump opens and closes inside the benchmark's pump.
+    ticks = [e for e in events if e[0] == "executor.tick"]
+    assert len(ticks) == 8
+    inside = lambda e: any(p[1] <= e[1] and e[2] <= p[2] for p in pumps)
+    for e in events:
+        if e[0] in ("executor.enqueue", "executor.dequeue",
+                    "executor.stage", "executor.account", "executor.tick"):
+            assert inside(e), e
+    # Metadata rides on the trace's events: at open, and set in the block.
+    dispatch = [e[3] for e in events if e[0] == "executor.dispatch"]
+    collect = [e[3] for e in events if e[0] == "executor.collect"]
+    assert [d["epoch"] for d in dispatch] == [0, 1]
+    assert all(d["bytes"] == 4 * 2 * 64 * 8 + 32 for d in dispatch)
+    assert [c["epoch"] for c in collect] == [0, 1]
+    assert sum(c["windows"] for c in collect) == 8
+    assert all(e[3]["items"] == 12 for e in events
+               if e[0] == "executor.enqueue")
+    assert {e[3]["tick"] for e in ticks} == set(range(1, 9))
 
 
 # ---------------------------------------------------------- publisher --
