@@ -6,9 +6,12 @@ lifecycle (init → subscribe → pump → stop):
 
 * ``start(pipeline, sources)`` subscribes every source's deliveries into
   per-shard bounded queues (``serve.queues``; shard i feeds level-0
-  node i).
+  node i). A delivery enters its queue as one chunk of arrays, so the
+  host path costs array operations per delivery, not Python steps per
+  item.
 * ``pump()`` is one tick: sources emit, queues batch-drain
-  (``get_many``), items stage into the active host buffer
+  (``get_many``, whole chunks and a slice of the last), items stage
+  into the active host buffer
   (``serve.staging``), and the straggler monitor scores each shard's
   arrival lag against its rolling deadline.
 * Every ``epoch_ticks`` pumps, the staged epoch dispatches to the
@@ -37,11 +40,14 @@ one per item — ``tick`` around the pump, inside it ``enqueue``
 around ``wait_device`` (the previous epoch), ``swap``, ``dispatch``
 (``run_epoch`` with its host-to-device copy) and ``collect``
 (``rows()`` and publication); ``drain`` wraps ``stop()``'s source-less
-ticks. ``dispatch`` and ``collect`` carry the epoch index. Counters:
-``executor_h2d_bytes`` (staged bytes handed to ``run_epoch``) and
+ticks. ``enqueue`` and ``dequeue`` carry the items they moved,
+``dequeue`` also the queue chunks its drains touched (``chunks``);
+``dispatch`` and ``collect`` carry the epoch index. Counters:
+``executor_h2d_bytes`` (staged bytes handed to ``run_epoch``),
 ``executor_collect_waited`` (collections that found the device still
-running). Spans never touch the data: windows are bitwise the same
-with the tracer on or off.
+running) and ``executor_queue_split_chunks`` (drains that sliced a
+chunk, leaving its tail queued). Spans never touch the data: windows
+are bitwise the same with the tracer on or off.
 
 Determinism: the epoch PRNG key is ``fold_in(pipeline.default_key,
 epoch_index)``, sources are passive between pumps, and the clock is
@@ -183,11 +189,17 @@ class StreamingExecutor:
                     if tracer.enabled:
                         meta["items"] = self._items_in() - in0
             with span("executor.dequeue") as meta:
+                chunks0 = self._chunk_reads() if tracer.enabled else None
                 drained = [q.get_many(self.max_records)
                            for q in self._queues]
                 arrived = np.asarray([v.size for v, _, _ in drained],
                                      np.int64)
                 meta["items"] = n_items = int(arrived.sum())
+                if chunks0 is not None:
+                    touched, split = self._chunk_reads()
+                    meta["chunks"] = touched - chunks0[0]
+                    tracer.count("executor_queue_split_chunks",
+                                 split - chunks0[1])
             with span("executor.stage", items=n_items):
                 for shard, (values, strata, arrivals) in enumerate(drained):
                     if values.size:
@@ -209,6 +221,12 @@ class StreamingExecutor:
 
     def _items_in(self) -> int:
         return sum(q.items_in for q in self._queues)
+
+    def _chunk_reads(self) -> tuple[int, int]:
+        """Chunks the queues' drains touched, and drains that sliced
+        one, so far."""
+        return (sum(q.chunks_touched for q in self._queues),
+                sum(q.chunks_split for q in self._queues))
 
     def _account(self, drain: bool, arrived, drops0: int, trunc0: int):
         """Straggler scores, the per-shard rate EWMA and the tick's Eq. 9

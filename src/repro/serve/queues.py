@@ -28,6 +28,17 @@ Accounting invariant (pinned in ``tests/test_serve_plane.py``):
 
 (``deferred`` counts offers that never entered, so it sits outside the
 identity on purpose.)
+
+Layout: the queue is a FIFO of *chunks*, not of items. A chunk is the
+items one ``put`` admitted, ``(values f32[k], strata i32[k], arrival)``:
+a copy the queue owns (a source may reuse its buffers), stamped with
+the call's arrival time. ``put`` and ``get_many`` cost a few array
+operations per chunk, whatever its length. Overflow is trimmed from the
+head (whole chunks, then a slice of the next), and a drain pops whole
+chunks and slices the last one, leaving its tail, with its arrival, at
+the head. The policies, the ``degrade`` RNG stream, every counter and
+the invariant are item-exact: the same calls admit, drop and return
+the same items in the same order as a FIFO of single items would.
 """
 from __future__ import annotations
 
@@ -49,13 +60,17 @@ class BoundedShardQueue:
                              f"valid: {POLICIES}")
         self.capacity = int(capacity)
         self.policy = policy
+        # (values f32[k], strata i32[k], arrival) chunks, oldest first
         self._q: collections.deque = collections.deque()
+        self._depth = 0
         self._rng = np.random.default_rng(seed)
         self.items_in = 0
         self.items_out = 0
         self.items_dropped = 0
         self.deferred = 0
         self.high_watermark = 0
+        self.chunks_touched = 0    # chunks get_many took items from
+        self.chunks_split = 0      # get_many calls that sliced a chunk
 
     # ------------------------------------------------------------- put --
     def put(self, values, strata, now: float) -> int:
@@ -67,33 +82,44 @@ class BoundedShardQueue:
         if offered == 0:
             return 0
         if self.policy == "block":
-            take = min(offered, self.capacity - len(self._q))
-            self.deferred += offered - take
-            self.items_in += take
-            for i in range(take):
-                self._q.append((float(values[i]), int(strata[i]), now))
-            accepted = take
-        elif self.policy == "drop_oldest":
+            accepted = min(offered, self.capacity - self._depth)
+            self.deferred += offered - accepted
+            self.items_in += accepted
+            self._append(values[:accepted], strata[:accepted], now)
+        else:
             self.items_in += offered
-            for i in range(offered):
-                self._q.append((float(values[i]), int(strata[i]), now))
-            while len(self._q) > self.capacity:
-                self._q.popleft()
-                self.items_dropped += 1
-            accepted = offered
-        else:  # degrade
-            self.items_in += offered
-            p_drop = len(self._q) / self.capacity
-            keep = self._rng.random(offered) >= p_drop
-            self.items_dropped += int(offered - keep.sum())
-            for i in np.flatnonzero(keep):
-                self._q.append((float(values[i]), int(strata[i]), now))
-            while len(self._q) > self.capacity:
-                self._q.popleft()
-                self.items_dropped += 1
-            accepted = int(keep.sum())
-        self.high_watermark = max(self.high_watermark, len(self._q))
+            if self.policy == "degrade":
+                keep = (self._rng.random(offered)
+                        >= self._depth / self.capacity)
+                values, strata = values[keep], strata[keep]
+                self.items_dropped += offered - values.size
+            accepted = int(values.size)
+            self._append(values, strata, now)
+            self._trim()
+        self.high_watermark = max(self.high_watermark, self._depth)
         return accepted
+
+    def _append(self, values, strata, now: float):
+        """Queue a copy of the batch as one chunk (none if it is empty)."""
+        if values.size:
+            self._q.append((values.copy(), strata.copy(), now))
+            self._depth += values.size
+
+    def _trim(self):
+        """Drop the oldest items past ``capacity``: whole chunks while
+        they fit in the excess, then the head of the next."""
+        excess = self._depth - self.capacity
+        while excess > 0:
+            values, strata, now = self._q[0]
+            if values.size <= excess:
+                self._q.popleft()
+                n = values.size
+            else:
+                self._q[0] = (values[excess:], strata[excess:], now)
+                n = excess
+            self._depth -= n
+            self.items_dropped += n
+            excess -= n
 
     # -------------------------------------------------------- get_many --
     def get_many(self, max_records: int
@@ -101,19 +127,35 @@ class BoundedShardQueue:
         """Kafka-``getmany``-style batched drain: up to ``max_records``
         items in FIFO order → ``(values f32[n], strata i32[n],
         arrivals f64[n])``."""
-        n = min(int(max_records), len(self._q))
-        values = np.empty(n, np.float32)
-        strata = np.empty(n, np.int32)
-        arrivals = np.empty(n, np.float64)
-        for i in range(n):
-            values[i], strata[i], arrivals[i] = self._q.popleft()
+        n = max(min(int(max_records), self._depth), 0)
+        values, strata, arrivals, sizes = [], [], [], []
+        left = n
+        while left:
+            v, s, now = self._q[0]
+            if v.size <= left:
+                self._q.popleft()
+            else:
+                self._q[0] = (v[left:], s[left:], now)
+                v, s = v[:left], s[:left]
+                self.chunks_split += 1
+            values.append(v)
+            strata.append(s)
+            arrivals.append(now)
+            sizes.append(v.size)
+            left -= v.size
+        self.chunks_touched += len(values)
+        self._depth -= n
         self.items_out += n
-        return values, strata, arrivals
+        if not n:
+            return (np.empty(0, np.float32), np.empty(0, np.int32),
+                    np.empty(0, np.float64))
+        return (np.concatenate(values), np.concatenate(strata),
+                np.repeat(np.asarray(arrivals, np.float64), sizes))
 
     # ------------------------------------------------------ accounting --
     @property
     def depth(self) -> int:
-        return len(self._q)
+        return self._depth
 
     @property
     def accounting_ok(self) -> bool:

@@ -22,6 +22,8 @@ All tests run with an injected fake clock and deterministic sources.
 """
 from __future__ import annotations
 
+import collections
+
 import numpy as np
 import pytest
 
@@ -123,6 +125,145 @@ def test_queue_rejects_bad_policy_and_capacity():
         BoundedShardQueue(capacity=4, policy="shrug")
     with pytest.raises(ValueError, match="capacity"):
         BoundedShardQueue(capacity=0)
+
+
+class _ItemQueue:
+    """The oracle: the bounded queue kept as a FIFO of single
+    ``(value, stratum, arrival)`` items, one Python step per item."""
+
+    def __init__(self, capacity: int, policy: str = "block", seed: int = 0):
+        self.capacity = int(capacity)
+        self.policy = policy
+        self._q: collections.deque = collections.deque()
+        self._rng = np.random.default_rng(seed)
+        self.items_in = 0
+        self.items_out = 0
+        self.items_dropped = 0
+        self.deferred = 0
+        self.high_watermark = 0
+
+    def put(self, values, strata, now: float) -> int:
+        values = np.asarray(values, np.float32)
+        strata = np.asarray(strata, np.int32)
+        offered = int(values.size)
+        if offered == 0:
+            return 0
+        if self.policy == "block":
+            take = min(offered, self.capacity - len(self._q))
+            self.deferred += offered - take
+            self.items_in += take
+            for i in range(take):
+                self._q.append((float(values[i]), int(strata[i]), now))
+            accepted = take
+        elif self.policy == "drop_oldest":
+            self.items_in += offered
+            for i in range(offered):
+                self._q.append((float(values[i]), int(strata[i]), now))
+            while len(self._q) > self.capacity:
+                self._q.popleft()
+                self.items_dropped += 1
+            accepted = offered
+        else:  # degrade
+            self.items_in += offered
+            p_drop = len(self._q) / self.capacity
+            keep = self._rng.random(offered) >= p_drop
+            self.items_dropped += int(offered - keep.sum())
+            for i in np.flatnonzero(keep):
+                self._q.append((float(values[i]), int(strata[i]), now))
+            while len(self._q) > self.capacity:
+                self._q.popleft()
+                self.items_dropped += 1
+            accepted = int(keep.sum())
+        self.high_watermark = max(self.high_watermark, len(self._q))
+        return accepted
+
+    def get_many(self, max_records: int):
+        n = min(int(max_records), len(self._q))
+        values = np.empty(n, np.float32)
+        strata = np.empty(n, np.int32)
+        arrivals = np.empty(n, np.float64)
+        for i in range(n):
+            values[i], strata[i], arrivals[i] = self._q.popleft()
+        self.items_out += n
+        return values, strata, arrivals
+
+    @property
+    def depth(self) -> int:
+        return len(self._q)
+
+
+_COUNTERS = ("items_in", "items_out", "items_dropped", "deferred",
+             "high_watermark", "depth")
+
+
+@pytest.mark.parametrize("policy", ["block", "drop_oldest", "degrade"])
+def test_chunked_queue_matches_the_per_item_queue(policy):
+    """Same calls, same answers: every drain returns the oracle's items,
+    strata and arrivals in its order, and every counter agrees after
+    every step, batches larger than the capacity included."""
+    rng = np.random.default_rng(11)
+    q = BoundedShardQueue(capacity=16, policy=policy, seed=5)
+    ref = _ItemQueue(capacity=16, policy=policy, seed=5)
+    for step in range(400):
+        if rng.random() < 0.5:
+            n = int(rng.integers(0, 41))
+            values = rng.normal(size=n)
+            strata = rng.integers(0, 4, size=n)
+            now = float(step) + 0.25
+            assert q.put(values, strata, now) == ref.put(values, strata, now)
+        else:
+            k = int(rng.integers(0, 51))
+            for got, want in zip(q.get_many(k), ref.get_many(k)):
+                assert got.dtype == want.dtype
+                np.testing.assert_array_equal(got, want)
+        for name in _COUNTERS:
+            assert getattr(q, name) == getattr(ref, name), (step, name)
+        assert q.accounting_ok
+
+
+def test_get_many_split_keeps_the_tail_in_order_with_its_arrival():
+    q = BoundedShardQueue(capacity=64, policy="block")
+    q.put(np.arange(5.0), np.arange(5), 1.0)
+    q.put(np.arange(5.0, 8.0), np.arange(5, 8), 2.0)
+    values, strata, arrivals = q.get_many(3)
+    np.testing.assert_array_equal(values, [0.0, 1.0, 2.0])
+    np.testing.assert_array_equal(arrivals, [1.0, 1.0, 1.0])
+    assert q.chunks_split == 1 and q.depth == 5
+    values, strata, arrivals = q.get_many(4)
+    np.testing.assert_array_equal(values, [3.0, 4.0, 5.0, 6.0])
+    np.testing.assert_array_equal(strata, [3, 4, 5, 6])
+    np.testing.assert_array_equal(arrivals, [1.0, 1.0, 2.0, 2.0])
+    assert q.chunks_touched == 3 and q.chunks_split == 2
+    values, _, arrivals = q.get_many(10)
+    np.testing.assert_array_equal(values, [7.0])
+    np.testing.assert_array_equal(arrivals, [2.0])
+    assert q.depth == 0 and q.accounting_ok
+
+
+def test_drop_oldest_trims_across_a_chunk_boundary():
+    q = BoundedShardQueue(capacity=6, policy="drop_oldest")
+    q.put(np.arange(3.0), np.zeros(3, np.int32), 1.0)
+    q.put(np.arange(3.0, 6.0), np.zeros(3, np.int32), 2.0)
+    # 4 more: the first chunk goes whole, the second loses its head.
+    q.put(np.arange(6.0, 10.0), np.zeros(4, np.int32), 3.0)
+    assert q.items_dropped == 4 and q.depth == 6
+    values, _, arrivals = q.get_many(10)
+    np.testing.assert_array_equal(values, [4.0, 5.0, 6.0, 7.0, 8.0, 9.0])
+    np.testing.assert_array_equal(arrivals, [2.0, 2.0, 3.0, 3.0, 3.0, 3.0])
+    assert q.accounting_ok
+
+
+@pytest.mark.parametrize("policy", ["block", "drop_oldest", "degrade"])
+def test_put_stores_a_copy(policy):
+    q = BoundedShardQueue(capacity=16, policy=policy)
+    values = np.arange(4, dtype=np.float32)
+    strata = np.arange(4, dtype=np.int32)
+    q.put(values, strata, 0.0)
+    values[:] = -1.0
+    strata[:] = -1
+    got_v, got_s, _ = q.get_many(4)
+    np.testing.assert_array_equal(got_v, [0.0, 1.0, 2.0, 3.0])
+    np.testing.assert_array_equal(got_s, [0, 1, 2, 3])
 
 
 # --------------------------------------------------------------- staging --
@@ -435,6 +576,42 @@ def test_executor_counters(monkeypatch):
         tr.counters["executor_h2d_bytes"]
     assert (("span", "executor.dispatch"),) in \
         fams["repro_span_seconds_total"]["samples"]
+
+
+def _split_drains(monkeypatch, tracer):
+    """Eight pumps of 6 items per shard drained 4 at a time: per shard
+    the drains touch 1, 2, 1, 1, 2, 1, 1, 2 chunks and slice one in all
+    but the third and sixth."""
+    from repro.obs import trace as trace_mod
+
+    monkeypatch.setattr(trace_mod, "_GLOBAL", tracer)
+    clock = FakeClock()
+    ex = _executor(clock, max_records=4)
+    ex.start(api.compile(_spec()),
+             [ConstantSource(0, rate=6, value=2.0, stratum=0),
+              ConstantSource(1, rate=6, value=3.0, stratum=1)],
+             warmup=False)
+    _run(ex, clock, 8)
+    return ex
+
+
+def test_dequeue_span_reads_chunks_and_the_split_counter(monkeypatch):
+    from repro.obs.trace import SpanTracer
+
+    tr = SpanTracer()
+    _split_drains(monkeypatch, tr)
+    chunks = [ev.meta["chunks"] for ev in tr.events
+              if ev.name == "executor.dequeue"]
+    assert chunks == [2 * c for c in (1, 2, 1, 1, 2, 1, 1, 2)]
+    assert tr.counters["executor_queue_split_chunks"] == 2 * 6
+    fams = parse_prometheus_text(metrics_text(tracer=tr))
+    assert fams["repro_executor_queue_split_chunks_total"]["samples"][()] \
+        == 12
+
+    off = SpanTracer(enabled=False)
+    ex = _split_drains(monkeypatch, off)
+    assert not off.events and not off.counters
+    assert [q.chunks_split for q in ex._queues] == [6, 6]
 
 
 def test_a_disabled_tracer_records_nothing_from_the_executor(monkeypatch):
