@@ -5,7 +5,9 @@ files are ``bench/configs/<config>.json`` and
 ``bench/traffic/<traffic>.json``. Per-layer metric readers are
 ``bench/layers/<metric>.py`` and kernel cost functions
 ``bench/costs/<kernel>.py``; both are loaded from their path, so a
-metric name may hold dots.
+metric name may hold dots. A configuration may state the chips its
+deployment spans (``"mesh": {"devices": N, "axis": "data"}``); without
+it the deployment is on one chip.
 """
 from __future__ import annotations
 
@@ -65,6 +67,28 @@ def capacity(config: dict, rates_per_tick) -> int:
     per_node = (float(sum(rates_per_tick)) * config["num_sources"]
                 / config["fanin"][0])
     return max(int(config["capacity_slack"] * per_node) + 256 & ~255, 1024)
+
+
+def chips(config: dict) -> int:
+    """Chips the deployment spans: its ``mesh.devices``, else 1."""
+    return int(config.get("mesh", {}).get("devices", 1))
+
+
+def mesh(config: dict):
+    """The deployment's device mesh: None where the configuration states
+    no ``mesh`` (one chip, the local pipeline), else the program's own
+    one-axis ``data`` mesh over ``mesh.devices`` chips, on which
+    ``api.compile`` lowers the spec to the SPMD pipeline (level-0 edge
+    node d on chip d)."""
+    layout = config.get("mesh")
+    if layout is None:
+        return None
+    if layout.get("axis") != "data":
+        raise ValueError(f"a configuration's mesh axis is 'data'; got "
+                         f"{layout.get('axis')!r}")
+    from repro.launch.analytics import make_data_mesh
+
+    return make_data_mesh(int(layout["devices"]))
 
 
 def pipeline_spec(config: dict, cap: int, seed: int):

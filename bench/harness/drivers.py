@@ -12,6 +12,13 @@ returns what the window published with the benchmark's own clock
 readings. The benchmark annotates its calls into the program
 (``run_epoch``, ``collect``, ``pump``, ``stop``) so that a device trace
 can attribute idle gaps to them.
+
+A pipeline on N chips (``api.compile(spec, mesh=...)``) is driven
+through the same calls as one on a single chip, with nothing converted
+on the side: ``Bulk`` calls ``run_epoch(state, key, values[T, n0,
+width], strata, counts)``, and ``Served`` hands the pipeline to
+``StreamingExecutor.start``, which reads ``fanin``. That front door is
+the harness's contract for N chips.
 """
 from __future__ import annotations
 

@@ -8,6 +8,12 @@ operand shapes the event carries, through ``bench/costs/<kernel>.py``.
 Idle gaps are attributed to the host span (the benchmark's own
 ``TraceAnnotation``s and the program's ``obs.span`` names) that
 overlaps them most.
+
+A cell on several chips traces one plane per chip, kept in chip order.
+Busy time and collective time are read on every chip and averaged;
+the breakdown (``top_ops``, ``idle_gaps``) and the per-program and
+per-kernel readings (``module_ms``, ``kernel_events``) are chip 0's,
+since every chip runs the same SPMD program.
 """
 from __future__ import annotations
 
@@ -63,13 +69,21 @@ def find_xplane(log_dir: str) -> str:
     return paths[-1]
 
 
+def _chip_index(plane_name: str, prefix: str) -> int:
+    m = re.match(r"\d+", plane_name[len(prefix):])
+    return int(m.group()) if m else 0
+
+
 def load(path: str, device_prefix: str = DEVICE_PREFIX,
          ops_line: str = OPS_LINE, modules_line: str = MODULES_LINE
          ) -> Trace:
+    """The trace's device planes that ran operations, in chip order
+    (``/device:TPU:0`` first), and the host spans named in
+    ``HOST_SPANS``."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
-    ops, modules, host = [], [], []
+    chips, host = [], []
     for plane in pd.planes:
         if plane.name.startswith(device_prefix):
             dev_ops, dev_modules = [], []
@@ -79,13 +93,15 @@ def load(path: str, device_prefix: str = DEVICE_PREFIX,
                 elif line.name == modules_line:
                     dev_modules.extend(_events(line))
             if dev_ops:
-                ops.append(sorted(dev_ops, key=lambda e: e.start))
-                modules.append(dev_modules)
+                chips.append((_chip_index(plane.name, device_prefix),
+                              sorted(dev_ops, key=lambda e: e.start),
+                              dev_modules))
         if plane.name.startswith("/host:"):
             for line in plane.lines:
                 host.extend(e for e in _events(line)
                             if e.name in HOST_SPANS)
-    return Trace(ops, modules, host)
+    chips.sort(key=lambda c: c[0])
+    return Trace([c[1] for c in chips], [c[2] for c in chips], host)
 
 
 def union(intervals) -> list:
@@ -99,16 +115,51 @@ def union(intervals) -> list:
     return out
 
 
+def busy_per_chip(trace: Trace) -> list:
+    """Seconds in which an operation ran, on each device."""
+    return [float(sum(e - s for s, e in union((ev.start, ev.end)
+                                              for ev in ops)))
+            for ops in trace.ops]
+
+
 def busy_seconds(trace: Trace) -> float:
     """Seconds in which an operation ran, averaged over the devices."""
-    per = [sum(e - s for s, e in union((ev.start, ev.end) for ev in ops))
-           for ops in trace.ops]
+    per = busy_per_chip(trace)
     return float(np.mean(per)) if per else 0.0
+
+
+_OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
+_COLLECTIVE = re.compile(r"^(%s)(-start|-done)?$"
+                         % "|".join(map(re.escape, COLLECTIVES)))
+
+
+def opcode(ev: Event) -> str:
+    """An operation's HLO opcode: from its HLO text
+    (``%all-reduce.3 = f32[4] all-reduce(...)``), or from its
+    instruction name (``all-reduce-start.1``) where the event carries
+    no text."""
+    if " = " in ev.name:
+        m = _OPCODE.search(ev.name.split(" = ", 1)[1])
+        return m.group(1) if m else ""
+    return re.sub(r"\.\d+$", "", ev.name.lstrip("%"))
+
+
+def collective_ms(trace: Trace) -> float | None:
+    """Milliseconds in which a collective operation (``COLLECTIVES``,
+    with their ``-start``/``-done`` halves) ran, on each chip over the
+    traced window, averaged over the chips. None where the trace holds
+    none."""
+    per = [sum(e - s for s, e in union((ev.start, ev.end) for ev in ops
+                                       if _COLLECTIVE.match(opcode(ev))))
+           for ops in trace.ops]
+    return 1e3 * float(np.mean(per)) if any(per) else None
 
 
 def module_ms(trace: Trace, pattern: str) -> float | None:
     """Mean milliseconds of one execution of the programs whose name
-    matches ``pattern`` (on the first device)."""
+    matches ``pattern`` (on chip 0: every chip runs the same program)."""
     rx = re.compile(pattern)
     durs = [ev.dur for ev in (trace.modules[0] if trace.modules else [])
             if rx.search(ev.name)]
@@ -116,10 +167,11 @@ def module_ms(trace: Trace, pattern: str) -> float | None:
 
 
 def kernel_events(trace: Trace, kernel: str) -> list:
-    """Executions of the Pallas kernel ``kernel`` on the first device:
-    on the TPU an operation's name is its HLO text, and a
-    ``pallas_call`` is the custom call named after the kernel's
-    function (``%cms_update.10 = f32[4,256]... custom-call(...)``)."""
+    """Executions of the Pallas kernel ``kernel`` on chip 0 (every chip
+    runs the same program): on the TPU an operation's name is its HLO
+    text, and a ``pallas_call`` is the custom call named after the
+    kernel's function (``%cms_update.10 = f32[4,256]...
+    custom-call(...)``)."""
     rx = re.compile(r"^%" + re.escape(kernel) + r"(\.\d+)? = .*custom-call\(")
     return [ev for ev in (trace.ops[0] if trace.ops else [])
             if rx.match(ev.name)]
@@ -154,7 +206,7 @@ def short_name(text: str) -> str:
     if " = " not in text:
         return text[:120]
     lhs, rhs = text.split(" = ", 1)
-    m = re.search(r"\s([a-z][a-z0-9\-]*)\(", rhs)
+    m = _OPCODE.search(rhs)
     shape = _SHAPE.search(rhs)
     parts = [lhs.lstrip("%")]
     if m:
@@ -166,8 +218,8 @@ def short_name(text: str) -> str:
 
 def idle_gaps(trace: Trace, t_start: float, t_stop: float, top: int = 10
               ) -> list:
-    """The longest idle gaps of the first device in the traced window,
-    each named by the host span that overlaps it most."""
+    """The longest idle gaps of chip 0 in the traced window, each named
+    by the host span that overlaps it most."""
     if not trace.ops:
         return []
     busy = union((ev.start, ev.end) for ev in trace.ops[0])
@@ -189,8 +241,9 @@ _CONTAINERS = re.compile(r"^\S+ = .*\b(while|conditional|call)\(")
 
 
 def top_ops(trace: Trace, top: int = 10) -> list:
-    """The device operations that took most time, by instruction, with
-    the control-flow operations that contain others left out."""
+    """The device operations that took most time on chip 0, by
+    instruction, with the control-flow operations that contain others
+    left out."""
     total: dict = {}
     for ev in (trace.ops[0] if trace.ops else []):
         if _CONTAINERS.match(ev.name):

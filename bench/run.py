@@ -14,8 +14,19 @@ output is one JSON object: ``correct``, ``attempted`` and ``failed``
 window), ``device``, and ``checks``, each number
 compared beside its limit. The same numbers end standard error.
 
-Without a TPU, or with fewer chips than the cell asks for, it exits 2
-and prints no result.
+A configuration states the chips its deployment spans under ``mesh``
+(``{"devices": N, "axis": "data"}``); without it the deployment is on
+one chip. The pipeline is ``api.compile(spec, mesh=cells.mesh(config))``
+and the drivers call it as they call a one-chip pipeline: that front
+door (``init``, ``default_key``, ``query_layout``, ``run_epoch`` on
+``values[T, n0, width]``, ``strata`` and ``counts``, and ``fanin`` for
+the streaming executor) is the harness's contract for N chips. On N
+chips ``memory_peak_bytes`` is the fullest chip's, beside
+``memory_peak_bytes_per_chip``, and a traced run's ``busy_s`` is the
+mean over the chips, beside ``busy_s_per_chip``.
+
+Without a TPU, or with fewer chips than the cell or its configuration
+asks for, it exits 2 and prints no result.
 """
 from __future__ import annotations
 
@@ -95,9 +106,9 @@ def main(argv=None, *, require_tpu: bool = True, config_override=None,
     entry, config, traffic = cells.cell(args.workload)
     config = config_override or config
     traffic = traffic_override or traffic
-    if require_tpu and (devices[0].platform != "tpu"
-                        or len(devices) < entry["chips"]):
-        print(f"bench: cell {args.workload} needs {entry['chips']} TPU "
+    chips = max(entry["chips"], cells.chips(config))
+    if (require_tpu and devices[0].platform != "tpu") or len(devices) < chips:
+        print(f"bench: cell {args.workload} needs {chips} TPU "
               f"chip(s); JAX sees {len(devices)} {devices[0].platform} "
               f"device(s). Nothing was run.", file=sys.stderr)
         return 2
@@ -113,7 +124,8 @@ def main(argv=None, *, require_tpu: bool = True, config_override=None,
     ring = stream.make_ring(config, traffic, args.seed,
                             int(traffic["ring_ticks"]))
     cap = cells.capacity(config, stream.tick_rates(config, traffic))
-    pipe = api.compile(cells.pipeline_spec(config, cap, args.seed))
+    pipe = api.compile(cells.pipeline_spec(config, cap, args.seed),
+                       mesh=cells.mesh(config))
     if wrap_pipeline is not None:
         pipe = wrap_pipeline(pipe)
     layout = pipe.query_layout()
@@ -153,8 +165,8 @@ def main(argv=None, *, require_tpu: bool = True, config_override=None,
     if args.trace:
         t_stop = time.perf_counter()
         jax.profiler.stop_trace()
-    stats = devices[0].memory_stats() or {}
-    peak = int(stats.get("peak_bytes_in_use", 0))
+    peak_mem = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                for d in devices[:chips]]
     window_s = run.t_end - run.t0
     log(window_s=window_s, ticks=run.due, epochs=run.epochs,
         items=run.items, windows=int(run.windows.tick.size),
@@ -183,7 +195,9 @@ def main(argv=None, *, require_tpu: bool = True, config_override=None,
         traced_s = t_stop - t_trace
         metrics, breakdown, busy_s = _per_layer(
             args.workload, config, run, trace, traced_s, devices[0])
-        device_extra = {"busy_s": busy_s, "window_s": traced_s}
+        device_extra = {"busy_s": busy_s,
+                        "busy_s_per_chip": tracing.busy_per_chip(trace),
+                        "window_s": traced_s}
     else:
         metrics = _end_to_end(args.workload, run, setup_s)
         breakdown, device_extra = None, {}
@@ -195,7 +209,9 @@ def main(argv=None, *, require_tpu: bool = True, config_override=None,
               "device": {"platform": devices[0].platform,
                          "kind": devices[0].device_kind,
                          "count": len(devices),
-                         "memory_peak_bytes": peak, **device_extra}}
+                         "memory_peak_bytes": max(peak_mem),
+                         "memory_peak_bytes_per_chip": peak_mem,
+                         **device_extra}}
     if breakdown is not None:
         result["breakdown"] = breakdown
     result["checks"] = checks
@@ -243,6 +259,7 @@ def _per_layer(cell: str, config: dict, run, trace, traced_s, device):
         run=run, trace=trace, config=config,
         roofline=roofline,
         module_ms=lambda pattern: tracing.module_ms(trace, pattern),
+        collective_ms=lambda: tracing.collective_ms(trace),
         idle_pct=lambda: 100.0 * (1.0 - busy_s / traced_s))
     metrics = {}
     for m in cells.metrics_of(cell, "per_layer"):

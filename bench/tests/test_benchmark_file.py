@@ -5,11 +5,35 @@ from __future__ import annotations
 import json
 import re
 
+import pytest
+
 from harness import cells
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 B = cells.benchmark()
+
+
+def chip_rule_errors(workloads: list, configs: dict) -> list:
+    """Where the cells break the layout rules: a cell takes 1 or 4
+    chips, as many as its configuration's ``mesh.devices`` (1 without a
+    ``mesh``), on the ``data`` axis, and no more than half of the cells,
+    rounded down, or one, take 4."""
+    errors = []
+    for w in workloads:
+        config = configs[w["config"]]
+        if w["chips"] not in (1, 4):
+            errors.append(f"{w['name']}: {w['chips']} chips")
+        if w["chips"] != cells.chips(config):
+            errors.append(f"{w['name']}: {w['chips']} chips for a "
+                          f"{cells.chips(config)}-chip configuration")
+        if "mesh" in config and config["mesh"].get("axis") != "data":
+            errors.append(f"{w['name']}: mesh axis "
+                          f"{config['mesh'].get('axis')!r}")
+    four = sum(w["chips"] == 4 for w in workloads)
+    if four > max(1, len(workloads) // 2):
+        errors.append(f"{four} of {len(workloads)} cells take 4 chips")
+    return errors
 
 
 def test_top_level_keys_and_command():
@@ -33,8 +57,11 @@ def test_names_units_and_entry_keys():
             c["reduced"]
     for w in B["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] == 1 and len(w["why"]) <= 200
+        assert len(w["why"]) <= 200
         assert (cells.BENCH / "traffic" / f"{w['traffic']}.json").exists()
+    configs = {c["name"]: cells.load_json(cells.ROOT / c["file"])
+               for c in B["configs"]}
+    assert chip_rule_errors(B["workloads"], configs) == []
     for m in B["end_to_end"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
                                           "source"}
@@ -65,3 +92,28 @@ def test_every_layer_reader_and_kernel_cost_loads():
         assert callable(cells.load_module("layers", m["name"]).read)
     for kernel in ("cms_update", "quantile_compact"):
         assert callable(cells.load_module("costs", kernel).cost)
+
+
+def _cells(*chips_and_configs):
+    return [{"name": f"c{i}", "config": config, "chips": chips}
+            for i, (chips, config) in enumerate(chips_and_configs)]
+
+
+@pytest.mark.parametrize("workloads, broken", [
+    (_cells((1, "one")), False),
+    (_cells((4, "mesh4"), (1, "one")), False),
+    (_cells((4, "mesh4")), False),
+    (_cells((4, "one"), (1, "one")), True),
+    (_cells((1, "mesh4"), (1, "one")), True),
+    (_cells((2, "mesh2"), (1, "one")), True),
+    (_cells((4, "mesh4-model"), (1, "one")), True),
+    (_cells((4, "mesh4"), (4, "mesh4"), (1, "one")), True),
+    (_cells((4, "mesh4"), (4, "mesh4"), (1, "one"), (1, "one")), False),
+], ids=["one-chip", "mesh4-beside-one", "mesh4-alone", "four-without-mesh",
+        "one-with-mesh4", "two-chips", "axis-not-data",
+        "two-of-three-take-four", "two-of-four-take-four"])
+def test_the_chip_rules(workloads, broken):
+    configs = {"one": {}, "mesh4": {"mesh": {"devices": 4, "axis": "data"}},
+               "mesh2": {"mesh": {"devices": 2, "axis": "data"}},
+               "mesh4-model": {"mesh": {"devices": 4, "axis": "model"}}}
+    assert bool(chip_rule_errors(workloads, configs)) == broken

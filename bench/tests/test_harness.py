@@ -3,9 +3,11 @@ the timed path broken underneath, which have to come out not correct."""
 from __future__ import annotations
 
 import json
+import os
 import shutil
 import subprocess
 import sys
+import textwrap
 
 import numpy as np
 import pytest
@@ -114,3 +116,70 @@ def test_the_benchmark_alone_exits_nonzero(tmp_path):
                        text=True, timeout=120,
                        env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin"})
     assert p.returncode != 0 and p.stdout == ""
+
+
+# A run of the bulk cell's small configuration stated on a 4-chip mesh,
+# in a process of its own with ``devices`` CPU devices. ``api.compile``
+# records the mesh it is given and returns the one-chip pipeline.
+_MESH_RUN = textwrap.dedent("""
+    import json, sys
+    sys.path[:0] = [{tests!r}, {bench!r}, {src!r}]
+    from conftest import small
+    from repro import api
+    import run
+
+    seen, real = [], api.compile
+
+    def compile(spec, *, mesh=None, **kw):
+        seen.append(None if mesh is None else
+                    [len(mesh.devices.flat), list(mesh.axis_names)])
+        return real(spec)
+
+    api.compile = compile
+    config, traffic = small("paper-testbed.bulk")
+    config["mesh"] = {{"devices": 4, "axis": "data"}}
+    rc = run.main(["--workload", "paper-testbed.bulk", "--seed",
+                   str(2 ** 31 + 29), "--seconds", "2", "--trace", "0"],
+                  require_tpu=False, config_override=config,
+                  traffic_override=traffic)
+    print(json.dumps({{"rc": rc, "meshes": seen}}))
+""")
+
+
+def _mesh_run(devices: int):
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env.update(JAX_PLATFORMS="cpu", XLA_FLAGS="--xla_force_host_platform_"
+               f"device_count={devices}")
+    code = _MESH_RUN.format(tests=str(cells.BENCH / "tests"),
+                            bench=str(cells.BENCH),
+                            src=str(cells.ROOT / "src"))
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=600, env=env)
+    assert p.returncode == 0, p.stderr[-3000:]
+    return p.stdout.strip().splitlines(), p.stderr
+
+
+def test_a_configuration_on_four_chips_compiles_onto_a_four_chip_mesh():
+    lines, err = _mesh_run(4)
+    done = json.loads(lines[-1])
+    assert done == {"rc": 0, "meshes": [[4, ["data"]]]}, err[-3000:]
+    result = json.loads(lines[-2])
+    assert result["correct"], result["checks"]
+    assert result["device"]["count"] == 4
+    per_chip = result["device"]["memory_peak_bytes_per_chip"]
+    assert len(per_chip) == 4
+    assert result["device"]["memory_peak_bytes"] == max(per_chip)
+
+
+def test_a_four_chip_configuration_on_one_device_runs_nothing():
+    lines, err = _mesh_run(1)
+    assert lines == [json.dumps({"rc": 2, "meshes": []})]
+    assert "needs 4 TPU chip(s)" in err and "Nothing was run" in err
+
+
+def test_a_configuration_without_a_mesh_is_on_one_chip():
+    _, config, _ = cells.cell("paper-testbed.bulk")
+    assert "mesh" not in config
+    assert cells.mesh(config) is None and cells.chips(config) == 1
+    with pytest.raises(ValueError, match="axis"):
+        cells.mesh({"mesh": {"devices": 4, "axis": "model"}})

@@ -106,3 +106,83 @@ def test_a_cpu_trace_reads_back(tmp_path):
             if "dot" in str(e.stats.get("hlo_op", ""))]
     assert len(dots) >= 3
     assert T.busy_seconds(trace) > 0.0
+
+
+AR = ("%all-reduce.3 = f32[4]{0} all-reduce(f32[4]{0} %p), channel_id=1, "
+      "replica_groups={{0,1,2,3}}, to_apply=%add")
+AG_START = ("%all-gather-start.1 = (f32[2]{0}, f32[8]{0}) all-gather-start("
+            "f32[2]{0} %q), channel_id=2, dimensions={0}")
+AG_DONE = ("%all-gather-done.1 = f32[8]{0} all-gather-done((f32[2]{0}, "
+           "f32[8]{0}) %all-gather-start.1)")
+FUSION = ("%fusion.4 = f32[4]{0} fusion(f32[4]{0} %all-reduce.3), "
+          "kind=kLoop, calls=%fused_computation.4")
+
+
+def test_opcode_from_hlo_text_or_instruction_name():
+    assert T.opcode(ev(AR, 0, 1)) == "all-reduce"
+    assert T.opcode(ev(AG_START, 0, 1)) == "all-gather-start"
+    assert T.opcode(ev(FUSION, 0, 1)) == "fusion"
+    assert T.opcode(ev("collective-permute-done.2", 0, 1)) == \
+        "collective-permute-done"
+    assert T.opcode(ev("%reduce-scatter.7", 0, 1)) == "reduce-scatter"
+
+
+def test_four_chips_busy_each_and_collectives_averaged():
+    # Chip d computes for 1 + d seconds, then waits in collectives; the
+    # fusion that reads a collective's result is no collective.
+    chips = []
+    for d in range(4):
+        ops = [ev(FUSION, 0.0, 1.0 + d), ev(AR, 5.0, 0.5),
+               ev(AG_START, 6.0, 0.25), ev(AG_DONE, 6.1, 0.25 * d),
+               ev("all-to-all.9", 8.0, 0.1)]
+        chips.append(ops)
+    trace = T.Trace(ops=chips, modules=[[]] * 4, host=[])
+    busy = [1.0 + d + 0.5 + max(0.25, 0.1 + 0.25 * d) + 0.1
+            for d in range(4)]
+    assert T.busy_per_chip(trace) == pytest.approx([1.85, 2.95, 4.2, 5.45])
+    assert T.busy_per_chip(trace) == pytest.approx(busy)
+    assert T.busy_seconds(trace) == pytest.approx(sum(busy) / 4)
+    # Per chip: 0.5 + the union of the all-gather halves + 0.1.
+    per = [0.5 + max(0.25, 0.1 + 0.25 * d) + 0.1 for d in range(4)]
+    assert T.collective_ms(trace) == pytest.approx(1e3 * sum(per) / 4)
+    one = T.Trace(ops=[[ev(FUSION, 0.0, 1.0)]], modules=[[]], host=[])
+    assert T.collective_ms(one) is None
+
+
+class _Plane:
+    def __init__(self, name, lines):
+        self.name, self.lines = name, lines
+
+
+class _Line:
+    def __init__(self, name, events):
+        self.name, self.events = name, events
+
+
+class _Ev:
+    def __init__(self, name, start_ns, duration_ns):
+        self.name, self.start_ns = name, start_ns
+        self.duration_ns, self.stats = duration_ns, []
+
+
+def test_load_keeps_one_op_list_per_chip_in_chip_order(monkeypatch):
+    from jax import profiler
+
+    def plane(d):
+        return _Plane(f"/device:TPU:{d}", [
+            _Line("XLA Ops", [_Ev(f"op{d}", 10, 5 * (d + 1))]),
+            _Line("XLA Modules", [_Ev(f"jit_epoch{d}", 0, 100)])])
+
+    planes = [plane(2), plane(0), _Plane("/host:CPU", [
+        _Line("python", [_Ev("pump", 0, 50), _Ev("other", 0, 5)])]),
+        plane(3), plane(1), _Plane("/device:TPU:4", [])]
+    monkeypatch.setattr(profiler, "ProfileData", type(
+        "PD", (), {"from_file": staticmethod(
+            lambda path: type("P", (), {"planes": planes})())}))
+    trace = T.load("unused.xplane.pb")
+    assert [ops[0].name for ops in trace.ops] == ["op0", "op1", "op2", "op3"]
+    assert [m[0].name for m in trace.modules] == [
+        "jit_epoch0", "jit_epoch1", "jit_epoch2", "jit_epoch3"]
+    assert [h.name for h in trace.host] == ["pump"]
+    assert T.busy_per_chip(trace) == pytest.approx([5e-9, 10e-9, 15e-9,
+                                                    20e-9])
