@@ -414,42 +414,6 @@ def main_path_phase(*, seed: int, rate: int = RATE, epochs: int = EPOCHS,
 
 
 # ------------------------------------------------------------------- mesh --
-def _mesh_batches(specs, cap: int, ticks: int, seed: int):
-    """The paper stream as the mesh path's interval batches: node ``d``'s
-    buffer (its two sources' items) is the ``d``-th contiguous shard of
-    each tick's item axis, so on a 4-device mesh device ``d`` is edge
-    node ``d``."""
-    from repro.core.types import IntervalBatch, StratumMeta
-    from repro.data import stream as S
-    import jax.numpy as jnp
-
-    n0 = FANIN[0]
-    per = NUM_SOURCES // n0
-    srcs = [S.StreamSource(specs, seed=seed * 100 + i)
-            for i in range(NUM_SOURCES)]
-    x = len(specs)
-    value = np.zeros((ticks, n0 * cap), np.float32)
-    stratum = np.zeros((ticks, n0 * cap), np.int32)
-    valid = np.zeros((ticks, n0 * cap), bool)
-    exact = []
-    for t in range(ticks):
-        tick_vals = []
-        for d in range(n0):
-            parts = [srcs[d * per + j].tick() for j in range(per)]
-            v = np.concatenate([p[0] for p in parts])[:cap]
-            s = np.concatenate([p[1] for p in parts])[:cap]
-            value[t, d * cap:d * cap + v.size] = v
-            stratum[t, d * cap:d * cap + v.size] = s
-            valid[t, d * cap:d * cap + v.size] = True
-            tick_vals.append(v)
-        exact.append(np.concatenate(tick_vals))
-    batches = IntervalBatch(
-        jnp.asarray(value), jnp.asarray(stratum), jnp.asarray(valid),
-        StratumMeta(jnp.ones((ticks, x), jnp.float32),
-                    jnp.zeros((ticks, x), jnp.float32)))
-    return batches, exact
-
-
 def mesh_phase(*, seed: int, n_devices: int = 4, rate: int = RATE,
                ticks: int = MESH_TICKS, expect_kernels: bool = True
                ) -> dict:
@@ -457,28 +421,38 @@ def mesh_phase(*, seed: int, n_devices: int = 4, rate: int = RATE,
     import jax.numpy as jnp
 
     from repro import api
+    from repro.data import stream as S
     from repro.launch.analytics import make_data_mesh
 
     spec, specs = paper_spec(rate, FRACTION)
     cap = spec.topology.capacity
-    batches, exact = _mesh_batches(specs, cap, 2 * ticks, seed)
-    halves = [jax.tree.map(lambda a, i=i: a[i * ticks:(i + 1) * ticks],
-                           batches) for i in (0, 1)]
+    # The paper stream as node rows [T, n0, cap]: on a 4-device mesh
+    # device d runs edge node d.
+    ingest = S.batch_ingest([S.StreamSource(specs, seed=seed * 100 + i)
+                             for i in range(NUM_SOURCES)],
+                            2 * ticks, FANIN[0], cap)
+    exact = [np.concatenate([ingest.values[t, d, :ingest.counts[t, d]]
+                             for d in range(FANIN[0])])
+             for t in range(2 * ticks)]
+    halves = [tuple(a[i * ticks:(i + 1) * ticks] for a in
+                    (ingest.values, ingest.strata, ingest.counts))
+              for i in (0, 1)]
     runs = {}
     for n in (n_devices, 1):
         pipe = api.compile(spec, mesh=make_data_mesh(n))
         t = time.perf_counter()
         if expect_kernels:
-            text = _lowered_text(pipe._fn, pipe.init(), pipe.default_key,
+            text = _lowered_text(pipe._fn, pipe.init(),
+                                 pipe.default_key,
                                  jnp.float32(pipe.local_budget),
-                                 halves[0])
+                                 *halves[0])
             _check("tpu_custom_call" in text,
                    f"the {n}-device epoch carries no Pallas kernel")
         state = pipe.init()
         answers, bounds, n_sampled, epoch_s = [], [], [], []
         for half in halves:                 # two epochs: state carries
             t0 = time.perf_counter()
-            state, wa = pipe.run_epoch(state, pipe.default_key, half)
+            state, wa = pipe.run_epoch(state, pipe.default_key, *half)
             jax.block_until_ready(wa.answers)
             epoch_s.append(time.perf_counter() - t0)
             _check(bool(np.asarray(wa.ok).all()), f"{n} devices: empty "

@@ -2,7 +2,14 @@
 
 The SPMD lowering of a ``PipelineSpec`` is the paper's §III-E hierarchy
 run in-graph across a mesh axis, one jitted dispatch per epoch of ``T``
-interval batches. Three lowerings share the front door:
+ticks. Its front door is the local pipeline's: ``run_epoch(state, key,
+values, strata, counts, budgets)`` on ``[T, fanin[0], width]`` node
+rows, with ``fanin``, ``init`` (a fresh state's first window is tick 1)
+and the routing surface. The node axis is split over the mesh axis in
+consecutive blocks, so with as many level-0 nodes as devices edge node
+``d`` runs on device ``d``; each device receives only its own rows and
+builds its interval batch (validity mask, source-identity metadata)
+inside the program. Three lowerings share the front door:
 
 * **Query tenants registered** (the full multi-tenant query plane):
   every device WHS-samples its shard of each window with the spec's
@@ -33,13 +40,15 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.api import spec as specmod
 from repro.api.pipeline import QueryRouting, WindowAnswers
 from repro.api.spec import PipelineSpec, SpecError
 from repro.core import tree as T
-from repro.core.types import IntervalBatch
+from repro.core.types import IntervalBatch, StratumMeta
 from repro.launch.sharding import spmd_epoch_specs, spmd_query_epoch_specs
+from repro.obs.trace import get_tracer
 
 
 def _check_vma(backend: str) -> bool:
@@ -51,6 +60,30 @@ def _check_vma(backend: str) -> bool:
     them; the check changes no value."""
     return (backend not in ("pallas", "pallas_fused")
             or jax.default_backend() == "tpu")
+
+
+def _node_rows_batch(values, strata, counts, num_strata: int
+                     ) -> IntervalBatch:
+    """One device's node rows ``[T, k, width]`` (``counts[T, k]`` live
+    items each, from the front) as the interval batch ``[T, k * width]``
+    its epoch samples, with the source-identity metadata (weight 1,
+    count 0). Traced inside the shard-mapped program, so no mask or
+    metadata crosses from the host."""
+    t, k, w = values.shape
+    valid = jnp.arange(w, dtype=jnp.int32) < counts[..., None]
+    flat = lambda a: a.reshape(t, k * w)
+    return IntervalBatch(flat(values), flat(strata), flat(valid),
+                         StratumMeta(jnp.ones((t, num_strata), jnp.float32),
+                                     jnp.zeros((t, num_strata),
+                                               jnp.float32)))
+
+
+def _typed(a, dtype):
+    """``a`` as ``dtype``, on the host for host input and on the device
+    for device arrays (never a round trip through the host)."""
+    if isinstance(a, jax.Array):
+        return a if a.dtype == dtype else a.astype(dtype)
+    return np.asarray(a, dtype)
 
 
 class SpmdPipelineState(NamedTuple):
@@ -106,9 +139,10 @@ def _spmd_program_entry(mesh, axis_name, core, max_budget, num_strata,
               num_strata=num_strata, allocation=allocation,
               sampler_backend=backend)
 
-    def epoch(state, key, budget, batches):
+    def spmd_epoch(state, key, budget, values, strata, counts):
         counter["traces"] += 1
-        n_ticks = batches.value.shape[0]
+        batches = _node_rows_batch(values, strata, counts, num_strata)
+        n_ticks = values.shape[0]
         local_q = jax.tree.map(lambda v: v[0], state.qstate)
         qfinal, outs = T.spmd_query_plane_epoch(
             key, state.tick, budget, batches, local_q, core, **kw)
@@ -143,14 +177,13 @@ def _spmd_program_entry(mesh, axis_name, core, max_budget, num_strata,
             telemetry=tel)
         return state2, (ts,) + outs
 
-    fn = jax.shard_map(
-        epoch, mesh=mesh,
-        in_specs=(state_spec, parts["replicated"], parts["replicated"],
-                  parts["batches"]),
-        out_specs=(state_spec, parts["replicated"]),
-        check_vma=_check_vma(backend))
-    entry = {"fn": jax.jit(fn, donate_argnums=(0,)),
-             "trace_counter": counter}
+    rep, rows = parts["replicated"], parts["rows"]
+    fn = jax.jit(jax.shard_map(
+        spmd_epoch, mesh=mesh,
+        in_specs=(state_spec, rep, rep, rows, rows, rows),
+        out_specs=(state_spec, rep), check_vma=_check_vma(backend)),
+        donate_argnums=(0,))
+    entry = {"fn": fn, "trace_counter": counter}
     _SPMD_PROGRAM_CACHE[sig] = entry
     return sig, entry
 
@@ -159,14 +192,17 @@ class CompiledSpmdPipeline(QueryRouting):
     """Immutable SPMD compilation of one ``PipelineSpec`` (see module
     doc for the three lowerings).
 
-    ``run_epoch(state, key, batches[, budgets])`` takes an
-    ``IntervalBatch`` whose leaves carry a leading tick axis
-    (``value[T, M]`` sharded over the mesh axis on M). With tenants it
-    returns ``(state', WindowAnswers)`` — per-window answers/bounds for
-    every tenant, replicated across the axis (every device evaluates the
-    root redundantly from the identical merged summaries; no single
-    point of failure). Without tenants it returns the legacy
-    ``(state, (sum, mean))`` per-tick ``QueryResult`` pair."""
+    ``run_epoch(state, key, values, strata, counts[, budgets])`` takes
+    the local pipeline's ingest: ``values``/``strata`` ``[T, fanin[0],
+    width]`` node rows, ``counts[T, fanin[0]]`` live items per row.
+    ``fanin[0]`` must be a multiple of the mesh axis: consecutive nodes
+    share a device, and each device samples its nodes' items to the
+    level-0 budget. With tenants it returns ``(state', WindowAnswers)``
+    — per-window answers/bounds for every tenant, replicated across the
+    axis (every device evaluates the root redundantly from the identical
+    merged summaries; no single point of failure). Without tenants it
+    returns the legacy ``(state, (sum, mean))`` per-tick
+    ``QueryResult`` pair."""
 
     def __init__(self, spec: PipelineSpec, mesh, *, axis_name: str = "data"):
         if axis_name not in mesh.axis_names:
@@ -174,9 +210,11 @@ class CompiledSpmdPipeline(QueryRouting):
                             f"(axes: {mesh.axis_names})")
         r = specmod.resolve(spec)
         self.spec = spec
+        self.fanin = list(spec.topology.fanin)
         self.mesh = mesh
         self.axis_name = axis_name
         self.n_devices = int(dict(mesh.shape)[axis_name])
+        self._rows_sharding = NamedSharding(mesh, P(None, axis_name))
         self.plan = r.plan
         self.tenant_names = tuple(t.name for t in spec.tenants)
         self.local_budget = int(r.sample_sizes[0])
@@ -184,7 +222,6 @@ class CompiledSpmdPipeline(QueryRouting):
         self.root_budget = int(r.sample_sizes[-1])
         self.telemetry_enabled = spec.telemetry.enabled
         self.trace_counter = {"traces": 0}
-        check_vma = _check_vma(spec.sampler.backend)
         if self.plan is not None:
             # Tenant lowering: merged-summary query plane. Spec
             # validation already guarantees mode == "whs" here (tenants
@@ -197,31 +234,33 @@ class CompiledSpmdPipeline(QueryRouting):
                 spec.sampler.backend, telemetry=self.telemetry_enabled)
             self._fn = entry["fn"]
             self.trace_counter = entry["trace_counter"]
-        elif spec.sampler.mode == "srs":
-            in_specs, out_specs = spmd_epoch_specs(axis_name)
+            return
+        num_strata = spec.topology.num_strata
+        if spec.sampler.mode == "srs":
             frac = float(spec.sampler.fraction)
 
-            def srs_epoch(key, batches):
+            def body(key, batches):
                 self.trace_counter["traces"] += 1
                 return T.spmd_srs_epoch(key, batches, axis_name=axis_name,
                                         fraction=frac)
-
-            fn = jax.shard_map(srs_epoch, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=check_vma)
-            self._fn = jax.jit(fn)
         else:
-            in_specs, out_specs = spmd_epoch_specs(axis_name)
-            kw = dict(axis_name=axis_name,
-                      num_strata=spec.topology.num_strata,
+            kw = dict(axis_name=axis_name, num_strata=num_strata,
                       local_budget=self.local_budget,
                       root_budget=self.root_budget,
                       allocation=spec.sampler.allocation,
                       sampler_backend=spec.sampler.backend)
-            fn = jax.shard_map(
-                lambda k, b: T.spmd_local_then_root_epoch(k, b, **kw),
-                mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check_vma)
-            self._fn = jax.jit(fn)
+
+            def body(key, batches):
+                return T.spmd_local_then_root_epoch(key, batches, **kw)
+
+        def spmd_epoch(key, values, strata, counts):
+            return body(key, _node_rows_batch(values, strata, counts,
+                                              num_strata))
+
+        in_specs, out_specs = spmd_epoch_specs(axis_name)
+        self._fn = jax.jit(jax.shard_map(
+            spmd_epoch, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=_check_vma(spec.sampler.backend)))
 
     # ---------------------------------------------------- tenant churn --
     def _with_plan(self, plan, tenants) -> "CompiledSpmdPipeline":
@@ -307,15 +346,15 @@ class CompiledSpmdPipeline(QueryRouting):
         return jax.random.PRNGKey(self.spec.seed)
 
     def init(self, key: jax.Array | None = None):
-        """Fresh explicit state. With tenants: global tick 0 plus one
-        empty sketch state per device (leaves ``[n_devices, ...]``).
-        Without tenants the path is stateless between intervals (each
-        interval batch is complete): empty pytree."""
+        """Fresh explicit state. With tenants: global tick 1 (the local
+        pipeline's numbering: a fresh state's first window is tick 1)
+        plus one empty sketch state per device (leaves
+        ``[n_devices, ...]``). Without tenants the path is stateless
+        between intervals (each interval batch is complete): empty
+        pytree."""
         del key
         if self.plan is None:
             return ()
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
         q0 = self.plan.init_state()
         # commit with the exact shardings the epoch fn emits, so every
         # epoch (first included) hits one compiled executable
@@ -323,7 +362,7 @@ class CompiledSpmdPipeline(QueryRouting):
             lambda v: jax.device_put(
                 jnp.stack([v] * self.n_devices),
                 NamedSharding(self.mesh, P(self.axis_name))), q0)
-        tick = jax.device_put(jnp.int32(0),
+        tick = jax.device_put(jnp.int32(1),
                               NamedSharding(self.mesh, P()))
         tel = ()
         if self.telemetry_enabled:
@@ -354,38 +393,53 @@ class CompiledSpmdPipeline(QueryRouting):
             budgets = np.asarray(budgets).reshape(-1)[0]
         return min(max(float(budgets), 1.0), float(self.max_local_budget))
 
-    def _check_batches(self, batches: IntervalBatch) -> None:
-        m = batches.value.shape[-1]
-        if m % self.n_devices:
+    def _place(self, values, strata, counts):
+        """The epoch's node rows on the mesh, split on the node axis:
+        each device receives only its own rows."""
+        n_ticks, n0 = np.shape(counts)
+        if n0 != self.fanin[0]:
+            raise SpecError(f"ingest rows must match level-0 nodes: got "
+                            f"{n0} for fanin {tuple(self.fanin)}")
+        if n0 % self.n_devices:
             raise SpecError(
-                f"the interval item axis ({m} slots) must divide evenly "
-                f"across mesh axis {self.axis_name!r} ({self.n_devices} "
-                f"devices) — pad the epoch batches to a multiple of the "
-                f"axis size (padding slots carry valid=False)")
+                f"fanin[0] = {n0} level-0 nodes must be a multiple of mesh "
+                f"axis {self.axis_name!r} ({self.n_devices} devices): "
+                f"each device runs a block of consecutive nodes")
+        with get_tracer().span("spmd.place", epoch=n_ticks,
+                               devices=self.n_devices) as meta:
+            rows = [_typed(values, np.float32), _typed(strata, np.int32),
+                    _typed(counts, np.int32)]
+            meta["bytes"] = sum(int(a.nbytes) for a in rows)
+            return jax.device_put(rows, self._rows_sharding)
 
-    def run_epoch(self, state, key: jax.Array, batches: IntervalBatch,
+    def run_epoch(self, state, key: jax.Array, values, strata, counts,
                   budgets=None):
-        """``T`` interval batches in one dispatch.
+        """Advance ``T = counts.shape[0]`` ticks in ONE dispatch, on the
+        local pipeline's ingest (see the class doc).
 
         Tenant path: window ``i`` folds the global tick ``state.tick+i``
         into ``key`` (multi-epoch runs resume bit-identically);
         ``state`` is donated — do not reuse the argument. ``budgets``
-        (traced) moves the applied level-0 sample budget with zero
-        retraces. Returns ``(state', WindowAnswers)``.
+        (traced; a scalar or the per-level list, ``clamp_budgets``)
+        moves the applied level-0 sample budget with zero retraces.
+        Returns ``(state', WindowAnswers)``.
 
         Legacy/no-tenant paths: stateless — tick ``i`` folds ``i`` into
         ``key``, bit-matching ``T`` per-interval calls; returns
         ``(state, (sum, mean))``."""
-        self._check_batches(batches)
+        if self.plan is None and budgets is not None:
+            raise SpecError("budgets are traced inputs of the tenant "
+                            "query plane only — the no-tenant SPMD "
+                            "path bakes the spec's budgets statically")
+        rows = self._place(values, strata, counts)
         if self.plan is None:
-            if budgets is not None:
-                raise SpecError("budgets are traced inputs of the tenant "
-                                "query plane only — the no-tenant SPMD "
-                                "path bakes the spec's budgets statically")
-            return state, self._fn(key, batches)
+            return state, self._fn(key, *rows)
         b = jnp.float32(self.clamp_budgets(budgets))
-        state, outs = self._fn(state, key, b, batches)
+        state, outs = self._fn(state, key, b, *rows)
         ts, ok, se, sv, me, mv, nsel, hist, ans, bnd = outs
+        # Host arithmetic on static shapes: it never waits for the epoch.
+        get_tracer().count("spmd_merge_bytes",
+                           ts.shape[0] * self.summary_bytes_per_window)
         tel = getattr(state, "telemetry", ())
         if hasattr(tel, "merge_bytes"):
             # The byte model depends on the LIVE tenant set (admit/retire
@@ -404,7 +458,7 @@ class CompiledSpmdPipeline(QueryRouting):
             n_sampled=nsel, histogram=hist, answers=ans, bounds=bnd,
             # no raw items ever cross a boundary on this path — the
             # would-be "forwarded items" channel is identically empty
-            n_forwarded=np.zeros((len(np.asarray(ts)), 1), np.int32))
+            n_forwarded=np.zeros((ts.shape[0], 1), np.int32))
         return state, wa
 
     @property

@@ -208,43 +208,6 @@ def ticks_to_ingest(tick_records, n_nodes: int, width: int) -> IngestBatch:
                        exact_count)
 
 
-def rows_to_interval_batch(values: np.ndarray, strata: np.ndarray,
-                           counts: np.ndarray, num_strata: int,
-                           width: int | None = None):
-    """Padded per-tick rows → the ``IntervalBatch``-with-tick-axis layout
-    the SPMD pipeline consumes (``repro.api.compile(spec, mesh=...)``).
-
-    ``values``/``strata`` are ``[T, W]`` padded rows with ``counts[T]``
-    live items each (``StreamSource.batch`` emits exactly this; host
-    record streams can go through ``ticks_to_ingest(..., n_nodes=1)``
-    first). ``width`` re-pads the item axis — pass a multiple of the
-    mesh axis size so the batch shards evenly; padding slots carry
-    ``valid=False`` and are never sampled. Metadata is the source
-    identity (weight 1, count 0) per tick.
-    """
-    import jax.numpy as jnp
-
-    from repro.core.types import IntervalBatch, StratumMeta
-
-    ticks, w0 = values.shape
-    width = int(width or w0)
-    if width != w0:
-        out_v = np.zeros((ticks, width), np.float32)
-        out_s = np.zeros((ticks, width), np.int32)
-        keep = min(w0, width)
-        out_v[:, :keep] = values[:, :keep]
-        out_s[:, :keep] = strata[:, :keep]
-        values, strata = out_v, out_s
-        counts = np.minimum(counts, width)
-    valid = np.arange(width)[None, :] < np.asarray(counts)[:, None]
-    return IntervalBatch(
-        value=jnp.asarray(values, jnp.float32),
-        stratum=jnp.asarray(strata, jnp.int32),
-        valid=jnp.asarray(valid),
-        meta=StratumMeta(jnp.ones((ticks, num_strata), jnp.float32),
-                         jnp.zeros((ticks, num_strata), jnp.float32)))
-
-
 class TokenStream:
     """LM training stream: ``num_strata`` domains with distinct unigram
     stats and arrival rates — the ApproxIoT strata for approx-training."""

@@ -562,9 +562,10 @@ def run_spmd_pipeline(specs, *, fraction: float = 0.1, ticks: int,
     merged-summary query plane → per-window answers. Returns a dict in
     the ``run_pipeline`` report style.
 
-    Every tick is ONE flat interval batch of the whole pod's arrivals,
-    sharded over the mesh axis on the item axis; ``epoch_ticks`` windows
-    batch into one jitted dispatch. With ``queries`` tenants the root
+    Each device runs one level-0 node: every tick the pod's arrivals
+    are dealt round robin over the nodes (``data.stream.ticks_to_ingest``)
+    and enter ``run_epoch`` as node rows; ``epoch_ticks`` windows batch
+    into one jitted dispatch. With ``queries`` tenants the root
     answers come from merged per-device sketch summaries (never raw
     reservoirs — see ``repro.api.spmd``); ``target_rel_error`` closes
     the §IV-B loop on the mesh: the per-epoch measured per-tenant error
@@ -577,12 +578,12 @@ def run_spmd_pipeline(specs, *, fraction: float = 0.1, ticks: int,
     n_dev = int(np.prod(list(mesh.shape.values())))
     src = S.StreamSource(specs, seed=seed * 977)
     per_tick = sum(sp.rate for sp in specs)
-    # item axis: offered load + Poisson slack, padded to shard evenly
-    width = int(1.35 * per_tick) + 256
-    width = -(-width // n_dev) * n_dev
+    # a node's buffer: its share of the offered load + Poisson slack
+    cap = -(-(int(1.35 * per_tick) + 256) // n_dev)
     if target_rel_error is not None and max_fraction is None:
         max_fraction = 1.0
-    spec = build_spec(specs, fraction=fraction, capacity=width // n_dev,
+    spec = build_spec(specs, fraction=fraction, capacity=cap,
+                      fanin=(n_dev, 1),
                       num_strata=len(specs), allocation=allocation,
                       seed=seed, mode=mode, sampler_backend=sampler_backend,
                       queries=queries, target_rel_error=target_rel_error,
@@ -590,6 +591,11 @@ def run_spmd_pipeline(specs, *, fraction: float = 0.1, ticks: int,
     pipe = api.compile(spec, mesh=mesh)
     epoch_t = min(epoch_ticks or 32, ticks)
     n_epochs = -(-ticks // epoch_t)
+
+    def node_rows(source):
+        b = S.ticks_to_ingest([source.tick() for _ in range(epoch_t)],
+                              n_nodes=n_dev, width=cap)
+        return b.values, b.strata, b.counts
 
     controller = None
     trajectory: list[dict] = []
@@ -608,11 +614,10 @@ def run_spmd_pipeline(specs, *, fraction: float = 0.1, ticks: int,
 
     state = pipe.init()
     if warmup:  # compile the epoch program off the measured clock
-        v, s, c = S.StreamSource(specs, seed=seed * 977 + 1).batch(
-            epoch_t, width)
-        b = S.rows_to_interval_batch(v, s, c, len(specs))
-        state, _ = pipe.run_epoch(state, pipe.default_key, b,
-                                  budgets=[budget] if pipe.plan else None)
+        state, _ = pipe.run_epoch(
+            state, pipe.default_key,
+            *node_rows(S.StreamSource(specs, seed=seed * 977 + 1)),
+            budgets=[budget] if pipe.plan else None)
         state = pipe.init()
         pipe.trace_counter["traces"] = 0
 
@@ -626,16 +631,14 @@ def run_spmd_pipeline(specs, *, fraction: float = 0.1, ticks: int,
     t0 = time.time()
     for e in range(n_epochs):
         with span("ingest", epoch=e):
-            v, s, c = src.batch(epoch_t, width)
-            exact_sum += float((v * (np.arange(width)[None, :]
-                                     < c[:, None])).sum())
-            exact_cnt += int(c.sum())
-            b = S.rows_to_interval_batch(v, s, c, len(specs))
+            ingest = node_rows(src)
+            exact_sum += float(ingest[0].sum())   # padding slots hold 0
+            exact_cnt += int(ingest[2].sum())
         if pipe.plan is not None:
             # the tenant path folds the carried GLOBAL tick into the key,
             # so one key gives fresh randomness every epoch
             with span("epoch_dispatch", epoch=e):
-                state, wa = pipe.run_epoch(state, pipe.default_key, b,
+                state, wa = pipe.run_epoch(state, pipe.default_key, *ingest,
                                            budgets=[budget])
             with span("block_until_ready"):
                 rows = pipe.rows(wa)
@@ -662,7 +665,7 @@ def run_spmd_pipeline(specs, *, fraction: float = 0.1, ticks: int,
             import jax
 
             k_e = jax.random.fold_in(pipe.default_key, e)
-            state, (sq, mq) = pipe.run_epoch(state, k_e, b)
+            state, (sq, mq) = pipe.run_epoch(state, k_e, *ingest)
             rows = [dict(tick=e * epoch_t + i,
                          sum=float(np.asarray(sq.estimate)[i]),
                          sum_var=float(np.asarray(sq.variance)[i]),

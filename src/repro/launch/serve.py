@@ -73,12 +73,14 @@ def serve_registry(window: int = 4) -> QueryRegistry:
 
 def telemetry_spec(capacity: int, fraction: float, seed: int = 0,
                    telemetry: bool = False,
-                   registry_fn=dashboard_registry) -> api.PipelineSpec:
+                   registry_fn=dashboard_registry,
+                   edge_nodes: int = EDGE_NODES) -> api.PipelineSpec:
     """The serving fleet's telemetry plane as one declarative spec:
-    per-request records → 2 edge aggregators → 1 datacenter root, the
-    dashboard (``registry_fn()``) as a query tenant on the shared tree."""
+    per-request records → ``edge_nodes`` edge aggregators → 1 datacenter
+    root, the dashboard (``registry_fn()``) as a query tenant on the
+    shared tree."""
     return api.PipelineSpec(
-        topology=api.TopologySpec(fanin=(EDGE_NODES, 1), capacity=capacity,
+        topology=api.TopologySpec(fanin=(edge_nodes, 1), capacity=capacity,
                                   num_strata=NUM_CLASSES),
         sampler=api.SamplerSpec(mode="whs", backend="topk",
                                 fraction=fraction),
@@ -108,9 +110,10 @@ def main(argv=None):
                          "caches")
     ap.add_argument("--mesh", type=int, default=None, metavar="N",
                     help="run the telemetry plane on an N-device 'data' "
-                         "mesh (repro.api.compile(spec, mesh=...)): each "
-                         "device samples its shard of every batch's "
-                         "records and the dashboard tenant answers from "
+                         "mesh (repro.api.compile(spec, mesh=...)), one "
+                         "edge aggregator per device: each device samples "
+                         "its aggregator's records and the dashboard "
+                         "tenant answers from "
                          "merged sketch summaries — no raw records cross "
                          "devices. CPU: export XLA_FLAGS=--xla_force_"
                          "host_platform_device_count=N")
@@ -206,27 +209,28 @@ def main(argv=None):
     # Each serving batch is one tick into the 2→1 hierarchy; the compiled
     # pipeline samples at every hop and the dashboard tenant's standing
     # queries are answered at the root each window — one fused dispatch
-    # for the whole epoch. With --mesh the SAME spec lowers onto the
-    # §III-E SPMD data plane instead: every device samples its shard of
-    # each batch's records and the dashboard tenant answers from MERGED
-    # sketch summaries — no raw record crosses a device boundary.
+    # for the whole epoch. With --mesh the same plane lowers onto the
+    # §III-E SPMD data plane instead, one edge aggregator per device:
+    # every device samples its aggregator's records and the dashboard
+    # tenant answers from MERGED sketch summaries — no raw record
+    # crosses a device boundary.
     capacity = max(64, args.batch)
     m = sum(len(v) for v, _ in tick_records)
     if args.mesh:
         from repro.launch.analytics import make_data_mesh
 
         pipe = api.compile(telemetry_spec(capacity, args.telemetry_fraction,
-                                          telemetry=args.telemetry),
+                                          telemetry=args.telemetry,
+                                          edge_nodes=args.mesh),
                            mesh=make_data_mesh(args.mesh))
         with span("ingest", ticks=len(tick_records)):
-            flat = S.ticks_to_ingest(tick_records, n_nodes=1, width=capacity)
-            width = -(-capacity // args.mesh) * args.mesh
-            batches = S.rows_to_interval_batch(
-                flat.values[:, 0], flat.strata[:, 0], flat.counts[:, 0],
-                NUM_CLASSES, width=width)
+            batch = S.ticks_to_ingest(tick_records, n_nodes=args.mesh,
+                                      width=capacity)
         state = pipe.init()
         with span("epoch_dispatch", ticks=len(tick_records)):
-            state, wa = pipe.run_epoch(state, pipe.default_key, batches)
+            state, wa = pipe.run_epoch(state, pipe.default_key,
+                                       batch.values, batch.strata,
+                                       batch.counts)
         with span("block_until_ready"):
             jax.block_until_ready(wa)
     else:

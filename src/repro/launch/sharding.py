@@ -210,18 +210,13 @@ def to_named(tree_specs, mesh: Mesh):
 
 def spmd_epoch_specs(axis_name: str = "data"):
     """(in_specs, out_specs) for the analytics SPMD epoch under
-    ``shard_map`` (``repro.api.compile(spec, mesh=...)``): epoch batches
-    are ``IntervalBatch``es with a leading tick axis — items sharded
-    over ``axis_name`` on the item axis, per-tick metadata sets
-    replicated; the root's (sum, mean) results are replicated (every
-    device computes the root stage redundantly)."""
-    from repro.core.types import IntervalBatch, StratumMeta
-
-    item = P(None, axis_name)
-    in_specs = (P(), IntervalBatch(item, item, item,
-                                   StratumMeta(P(), P())))
-    out_specs = (P(), P())
-    return in_specs, out_specs
+    ``shard_map`` (``repro.api.compile(spec, mesh=...)``): the key is
+    replicated and the epoch's ``[T, fanin[0], ...]`` node rows
+    (values, strata, counts) are split over ``axis_name`` on the node
+    axis; the root's (sum, mean) results are replicated (every device
+    computes the root stage redundantly)."""
+    rows = P(None, axis_name)
+    return (P(), rows, rows, rows), (P(), P())
 
 
 def spmd_query_epoch_specs(axis_name: str, qstate):
@@ -231,17 +226,14 @@ def spmd_query_epoch_specs(axis_name: str, qstate):
     Per-device sketch state leaves carry a leading device axis sharded
     over ``axis_name`` (each device owns exactly its own quantile
     buffers / CM tables / top-k slots — they all-gather as O(sketch)
-    summaries at the window boundary, never as items); epoch batches
-    stay item-sharded on their trailing axis; everything the root
-    returns (per-window answers, bounds, built-in workload) is
-    replicated. Returns ``dict(qstate=..., batches=..., replicated=P())``
-    — components, because the caller owns the state/output pytree
-    structure they assemble into."""
-    from repro.core.types import IntervalBatch, StratumMeta
-
-    item = P(None, axis_name)
+    summaries at the window boundary, never as items); the epoch's node
+    rows are split on their node axis; everything the root returns
+    (per-window answers, bounds, built-in workload) is replicated.
+    Returns ``dict(qstate=..., rows=..., replicated=P())`` — components,
+    because the caller owns the state/output pytree structure they
+    assemble into."""
     return dict(
         qstate=jax.tree.map(lambda _: P(axis_name), qstate),
-        batches=IntervalBatch(item, item, item, StratumMeta(P(), P())),
+        rows=P(None, axis_name),
         replicated=P(),
     )

@@ -13,10 +13,15 @@ bounded ring buffer. The canonical span names the drivers emit:
   ``enqueue``, ``dequeue``, ``stage``, ``account``; at an epoch edge
   ``flush``, ``wait_device``, ``swap``, ``dispatch``, ``collect``; and
   ``drain`` around ``stop()``'s source-less ticks
+* ``spmd.place``        — the mesh pipeline's host work of one epoch:
+  its node rows typed and put onto the mesh, split on the node axis
+  (``epoch`` ticks, ``bytes`` handed over, ``devices``)
 
 Counters (:meth:`SpanTracer.count`) are snake_case names, exposed as
 ``repro_<name>_total``; the executor bumps ``executor_h2d_bytes``,
-``executor_collect_waited`` and ``executor_queue_split_chunks``.
+``executor_collect_waited`` and ``executor_queue_split_chunks``, the
+mesh pipeline ``spmd_merge_bytes`` (per dispatched epoch, its windows
+times ``summary_bytes_per_window``).
 
 Export with :meth:`SpanTracer.chrome_trace` / :meth:`SpanTracer.save`:
 the JSON loads directly in ``chrome://tracing`` and

@@ -385,8 +385,11 @@ def test_compile_with_mesh_matches_spmd_epoch():
     spec = _spec(sizes=(32, 32, 64))
     pipe = api.compile(spec, mesh=mesh)
     assert pipe.local_budget == 32 and pipe.root_budget == 64
-    state, (s_t, m_t) = pipe.run_epoch(pipe.init(), pipe.default_key,
-                                       batches)
+    n0 = spec.topology.fanin[0]
+    rows = (np.asarray(batches.value).reshape(ticks, n0, m // n0),
+            np.asarray(batches.stratum).reshape(ticks, n0, m // n0),
+            np.full((ticks, n0), m // n0, np.int32))
+    state, (s_t, m_t) = pipe.run_epoch(pipe.init(), pipe.default_key, *rows)
 
     spec1 = IntervalBatch(P("data"), P("data"), P("data"),
                           StratumMeta(P(), P()))
@@ -418,4 +421,4 @@ def test_compile_with_mesh_accepts_tenants_and_srs():
                            mesh=mesh)
     assert tenanted.plan is not None
     assert tenanted.tenant_names == ("a",)
-    assert int(tenanted.init().tick) == 0
+    assert int(tenanted.init().tick) == 1

@@ -196,16 +196,16 @@ _SPMD_HARNESS = textwrap.dedent("""
     from repro import api
     from repro.api.spec import (PipelineSpec, SamplerSpec, TelemetrySpec,
                                 TenantSpec, TopologySpec)
-    from repro.data import stream as S
+    from repro.obs.trace import get_tracer
     from repro.query.registry import QueryRegistry
 
-    T, M, X = 6, 512, 2
+    T, M, X, N0 = 6, 512, 2, 4
 
     def spec(telemetry):
         reg = (QueryRegistry().register_sum().register_mean()
                .register_quantile("q", (0.5, 0.9), capacity=64))
         return PipelineSpec(
-            topology=TopologySpec(fanin=(1,), capacity=M,
+            topology=TopologySpec(fanin=(N0, 1), capacity=M,
                                   num_strata=X),
             sampler=SamplerSpec(mode="whs", backend="topk",
                                 fraction=0.25),
@@ -214,10 +214,10 @@ _SPMD_HARNESS = textwrap.dedent("""
             seed=5)
 
     rng = np.random.default_rng(0)
-    vals = rng.normal(40.0, 8.0, (T, M)).astype(np.float32)
-    strs = rng.integers(0, X, (T, M)).astype(np.int32)
-    counts = np.full((T,), M, np.int64)
-    batches = S.rows_to_interval_batch(vals, strs, counts, X)
+    # node rows: level-0 node d (a quarter of each tick) on device d
+    vals = rng.normal(40.0, 8.0, (T, N0, M // N0)).astype(np.float32)
+    strs = rng.integers(0, X, (T, N0, M // N0)).astype(np.int32)
+    counts = np.full((T, N0), M // N0, np.int32)
     mesh = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4])
 
     out = {}
@@ -225,7 +225,9 @@ _SPMD_HARNESS = textwrap.dedent("""
     for tel in (True, False):
         pipe = api.compile(spec(tel), mesh=mesh)
         state = pipe.init()
-        state, wa = pipe.run_epoch(state, pipe.default_key, batches)
+        counter0 = get_tracer().counters["spmd_merge_bytes"]
+        state, wa = pipe.run_epoch(state, pipe.default_key, vals, strs,
+                                   counts)
         answers[tel] = [np.asarray(x).tolist()
                         for x in jax.tree.leaves(wa)]
         if tel:
@@ -233,8 +235,12 @@ _SPMD_HARNESS = textwrap.dedent("""
             out["windows"] = snap["windows"]
             out["merge_bytes"] = snap["merge_bytes"]
             out["bytes_per_window"] = pipe.summary_bytes_per_window
+            out["merge_counter"] = (get_tracer().counters["spmd_merge_bytes"]
+                                    - counter0)
+            out["ticks"] = T
             n0 = pipe.trace_counter["traces"]
-            state, _ = pipe.run_epoch(state, pipe.default_key, batches)
+            state, _ = pipe.run_epoch(state, pipe.default_key, vals, strs,
+                                      counts)
             out["retraced"] = pipe.trace_counter["traces"] - n0
     out["bitwise"] = answers[True] == answers[False]
     print(json.dumps(out))
@@ -264,6 +270,9 @@ def test_spmd_merge_bytes_law(spmd):
     assert spmd["windows"] > 0
     assert spmd["merge_bytes"] == pytest.approx(
         spmd["windows"] * spmd["bytes_per_window"])
+    # The tracer's counter is host arithmetic over every dispatched
+    # window, flushed or not: it never reads the device.
+    assert spmd["merge_counter"] == spmd["ticks"] * spmd["bytes_per_window"]
 
 
 def test_spmd_second_epoch_no_retrace(spmd):

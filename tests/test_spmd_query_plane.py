@@ -38,10 +38,9 @@ _HARNESS = textwrap.dedent("""
     from repro import api
     from repro.api.spec import (PipelineSpec, SamplerSpec, TenantSpec,
                                 TopologySpec)
-    from repro.data import stream as S
     from repro.query.registry import QueryRegistry
 
-    X, T, M = 3, 4, 8192
+    X, T, M, N0 = 3, 4, 8192, 8
     HEAVY = np.array([7, 13, 29, 101], np.int64)
 
     rng = np.random.default_rng(0)
@@ -50,8 +49,12 @@ _HARNESS = textwrap.dedent("""
         rng.choice(HEAVY, p=[0.5, 0.3, 0.15, 0.05], size=(T, M)),
         np.round(rng.normal(50.0, 9.0, (T, M)))).astype(np.float32)
     strs = rng.integers(0, X, (T, M)).astype(np.int32)
-    counts = np.full((T,), M, np.int64)
-    batches = S.rows_to_interval_batch(vals, strs, counts, X)
+    # the stream as node rows [T, N0, M // N0]: on n devices device d
+    # runs nodes [d*N0/n, (d+1)*N0/n), consecutive blocks of items
+    def node_rows(v, s):
+        return (v.reshape(T, N0, M // N0), s.reshape(T, N0, M // N0),
+                np.full((T, N0), M // N0, np.int32))
+    rows = node_rows(vals, strs)
 
     def tenants():
         a = (QueryRegistry().register_sum().register_count()
@@ -65,7 +68,7 @@ _HARNESS = textwrap.dedent("""
 
     def make_spec(fraction, mode="whs", with_tenants=True):
         return PipelineSpec(
-            topology=TopologySpec(fanin=(4, 2, 1), capacity=M // 8,
+            topology=TopologySpec(fanin=(N0, 1), capacity=M // N0,
                                   num_strata=X),
             sampler=SamplerSpec(mode=mode, backend="topk",
                                 fraction=fraction),
@@ -86,7 +89,7 @@ _HARNESS = textwrap.dedent("""
     runs = {}
     for n in (1, 2, 4, 8):
         pipe = api.compile(spec, mesh=mesh_of(n))
-        st, wa = pipe.run_epoch(pipe.init(), pipe.default_key, batches)
+        st, wa = pipe.run_epoch(pipe.init(), pipe.default_key, *rows)
         runs[n] = dict(answers=np.asarray(wa.answers).tolist(),
                        bounds=np.asarray(wa.bounds).tolist(),
                        n_sampled=np.asarray(wa.n_sampled).tolist(),
@@ -123,14 +126,14 @@ _HARNESS = textwrap.dedent("""
     # caps keep multi-strata fraction-1.0 merely near-exact), so every
     # weight is exactly 1 and the sketch holds the raw stream
     spec_exact = PipelineSpec(
-        topology=TopologySpec(fanin=(4, 2, 1), capacity=M // 8,
+        topology=TopologySpec(fanin=(N0, 1), capacity=M // N0,
                               num_strata=1),
         sampler=SamplerSpec(mode="whs", backend="topk", fraction=1.0),
         tenants=tenants(), seed=0)
-    batches1 = S.rows_to_interval_batch(vals, np.zeros_like(strs), counts, 1)
+    rows1 = node_rows(vals, np.zeros_like(strs))
     pipe1 = api.compile(spec_exact, mesh=mesh_of(8))
     assert pipe1.local_budget == M // 8
-    st, wa = pipe1.run_epoch(pipe1.init(), pipe1.default_key, batches1)
+    st, wa = pipe1.run_epoch(pipe1.init(), pipe1.default_key, *rows1)
     out["exact_regime"] = dict(answers=np.asarray(wa.answers).tolist(),
                                bounds=np.asarray(wa.bounds).tolist())
     out["exact_regime_q_ranks"] = [
@@ -140,9 +143,9 @@ _HARNESS = textwrap.dedent("""
     # ---- multi-epoch resume (4 devices): 2+2 ticks ≡ 4 ticks -------------
     pipe = api.compile(spec, mesh=mesh_of(4))
     stA, waA = pipe.run_epoch(pipe.init(), pipe.default_key,
-                              jax.tree.map(lambda v: v[:2], batches))
+                              *(r[:2] for r in rows))
     stA, waB = pipe.run_epoch(stA, pipe.default_key,
-                              jax.tree.map(lambda v: v[2:], batches))
+                              *(r[2:] for r in rows))
     two = np.concatenate([np.asarray(waA.answers), np.asarray(waB.answers)])
     one = np.asarray(runs[4]["answers"])
     out["resume"] = dict(
@@ -156,7 +159,7 @@ _HARNESS = textwrap.dedent("""
     for n in (1, 8):
         pipe = api.compile(make_spec(0.25, mode="srs", with_tenants=False),
                            mesh=mesh_of(n))
-        _, (sq, mq) = pipe.run_epoch(pipe.init(), pipe.default_key, batches)
+        _, (sq, mq) = pipe.run_epoch(pipe.init(), pipe.default_key, *rows)
         srs[n] = dict(sum=np.asarray(sq.estimate).tolist(),
                       sum_var=np.asarray(sq.variance).tolist(),
                       mean=np.asarray(mq.estimate).tolist(),
@@ -180,10 +183,9 @@ _HARNESS = textwrap.dedent("""
                     elif hasattr(j, "eqns"):
                         walk(j, acc)
     pipe = api.compile(spec, mesh=mesh_of(8))
-    closed = jax.make_jaxpr(
-        lambda st, k, b, bt: pipe._fn(st, k, b, bt))(
+    closed = jax.make_jaxpr(pipe._fn)(
         pipe.init(), pipe.default_key, jnp.float32(pipe.local_budget),
-        batches)
+        *rows)
     acc = []
     walk(closed.jaxpr, acc)
     out["collectives"] = acc
@@ -216,7 +218,7 @@ def test_eight_devices_simulated(harness):
     assert harness["n_devices_seen"] == 8
     for n, r in harness["tenant_runs"].items():
         assert np.asarray(r["ok"]).all()
-        assert r["tick"] == [0, 1, 2, 3]
+        assert r["tick"] == [1, 2, 3, 4]
 
 
 def test_exact_queries_bitwise_across_device_counts(harness):
@@ -321,7 +323,7 @@ def test_multi_epoch_resume_bitwise(harness):
     """Two 2-tick epochs through the donated state produce bitwise the
     answers of one 4-tick epoch — global-tick key folding plus carried
     sketch state make the epoch boundary invisible."""
-    assert harness["resume"]["ticks"] == [0, 1, 2, 3]
+    assert harness["resume"]["ticks"] == [1, 2, 3, 4]
     assert harness["resume"]["bitwise"], harness["resume"]
     assert harness["resume"]["max_abs_diff"] == 0.0
 
@@ -401,13 +403,14 @@ def _tenant_spec(capacity=1024, fraction=0.25, seed=0):
         seed=seed)
 
 
-def _batches(ticks=2, m=2048, seed=0):
-    from repro.data import stream as S
-
+def _rows(ticks=2, m=2048, seed=0, n0=4):
+    """``m`` items per tick as ``[ticks, n0, m // n0]`` node rows."""
     rng = np.random.default_rng(seed)
     vals = np.round(rng.normal(50, 9, (ticks, m))).astype(np.float32)
     strs = rng.integers(0, X, (ticks, m)).astype(np.int32)
-    return S.rows_to_interval_batch(vals, strs, np.full((ticks,), m), X)
+    return (vals.reshape(ticks, n0, m // n0),
+            strs.reshape(ticks, n0, m // n0),
+            np.full((ticks, n0), m // n0, np.int32))
 
 
 def _mesh1():
@@ -424,10 +427,9 @@ def test_spmd_epoch_one_dispatch_donated_zero_retrace():
     from repro import api
 
     pipe = api.compile(_tenant_spec(), mesh=_mesh1())
-    batches = _batches(2)
     s0 = pipe.init()
     q_before = s0.qstate
-    s1, wa1 = pipe.run_epoch(s0, pipe.default_key, batches)
+    s1, wa1 = pipe.run_epoch(s0, pipe.default_key, *_rows(2))
     traces = pipe.trace_counter["traces"]
     assert traces == 1
     n_small = int(np.asarray(wa1.n_sampled)[-1])
@@ -435,7 +437,7 @@ def test_spmd_epoch_one_dispatch_donated_zero_retrace():
     with pytest.raises(RuntimeError):
         np.asarray(jax.tree.leaves(q_before)[0])
     # budget move: bigger sample, same executable (budgets are traced)
-    s2, wa2 = pipe.run_epoch(s1, pipe.default_key, _batches(2, seed=1),
+    s2, wa2 = pipe.run_epoch(s1, pipe.default_key, *_rows(2, seed=1),
                              budgets=[pipe.max_local_budget])
     assert pipe.trace_counter["traces"] == traces, "budget move retraced!"
     assert int(np.asarray(wa2.n_sampled)[-1]) > n_small
@@ -447,8 +449,7 @@ def test_spmd_epoch_one_dispatch_donated_zero_retrace():
     # tick never re-traces
     cache_after_two = pipe._fn._cache_size()
     assert cache_after_two <= 2
-    pipe.run_epoch(s2, pipe.default_key, _batches(2, seed=2),
-                   budgets=[64])
+    pipe.run_epoch(s2, pipe.default_key, *_rows(2, seed=2), budgets=[64])
     assert pipe._fn._cache_size() == cache_after_two
     assert pipe.trace_counter["traces"] == traces
 
@@ -463,30 +464,21 @@ def test_spmd_budgets_rejected_without_tenants():
     plain = dataclasses.replace(spec, tenants=())
     pipe = api.compile(plain, mesh=_mesh1())
     with pytest.raises(SpecError, match="tenant"):
-        pipe.run_epoch(pipe.init(), pipe.default_key, _batches(1),
+        pipe.run_epoch(pipe.init(), pipe.default_key, *_rows(1),
                        budgets=[64])
 
 
 def test_spmd_rejects_indivisible_item_axis():
-    """Actionable error for a genuinely unsupported layout: the item
-    axis must shard evenly over the mesh."""
-    import jax
-
+    """Actionable error for a genuinely unsupported layout: the node
+    axis must shard evenly over the mesh (the real 4-device path is
+    ``test_spmd_front_door.py``)."""
     from repro import api
     from repro.api.spec import SpecError
 
-    if len(jax.devices()) < 2:
-        # build the 2-way mesh error by padding to an odd width on 1 dev
-        pipe = api.compile(_tenant_spec(), mesh=_mesh1())
-        pipe.n_devices = 2   # simulate the check's arithmetic
-        with pytest.raises(SpecError, match="divide evenly"):
-            pipe._check_batches(_batches(1, m=2049))
-    else:  # pragma: no cover — multi-device hosts check the real path
-        mesh = jax.make_mesh((2,), ("data",), devices=jax.devices()[:2])
-        pipe = api.compile(_tenant_spec(), mesh=mesh)
-        with pytest.raises(SpecError, match="divide evenly"):
-            pipe.run_epoch(pipe.init(), pipe.default_key,
-                           _batches(1, m=2049))
+    pipe = api.compile(_tenant_spec(), mesh=_mesh1())
+    pipe.n_devices = 3   # simulate the check's arithmetic: 4 nodes on 3
+    with pytest.raises(SpecError, match="multiple of mesh axis"):
+        pipe.run_epoch(pipe.init(), pipe.default_key, *_rows(1))
 
 
 def test_local_compile_unchanged_by_spmd_lowering():
@@ -499,17 +491,10 @@ def test_local_compile_unchanged_by_spmd_lowering():
     from repro.core.tree import HostTree
 
     spec = _tenant_spec(capacity=768, fraction=0.125)
-    batches = _batches(3, m=700)
-    vals = np.asarray(batches.value)
-    strs = np.asarray(batches.stratum)
-
     pipe = api.compile(spec)
     # local runtime consumes [T, n0, width] node-major ingest
     n0 = spec.topology.fanin[0]
-    width = 700 // n0
-    v4 = vals[:, :n0 * width].reshape(3, n0, width)
-    s4 = strs[:, :n0 * width].reshape(3, n0, width)
-    c4 = np.full((3, n0), width)
+    v4, s4, c4 = _rows(3, m=700, n0=n0)
     state, wa = pipe.run_epoch(pipe.init(), pipe.default_key, v4, s4, c4)
     rows = pipe.rows(wa)
 
@@ -543,10 +528,14 @@ def test_spmd_clt_coverage_vmapped():
 
     pipe = api.compile(_tenant_spec(fraction=0.125), mesh=_mesh1())
     plan = pipe.plan
-    batches = _batches(1, m=2048, seed=7)
-    batch = jax.tree.map(lambda v: v[0], batches)    # one window
-    exact_sum = float(np.asarray(batches.value).sum())
-    exact_mean = float(np.asarray(batches.value).mean())
+    vals, strs, _ = _rows(1, m=2048, seed=7)
+    batch = IntervalBatch(                            # one window
+        jnp.asarray(vals.reshape(-1)), jnp.asarray(strs.reshape(-1)),
+        jnp.ones((2048,), bool),
+        StratumMeta(jnp.ones((X,), jnp.float32),
+                    jnp.zeros((X,), jnp.float32)))
+    exact_sum = float(vals.sum())
+    exact_mean = float(vals.mean())
     n_draws = 200
 
     # the vmap runs INSIDE the shard-mapped program (vmapped collectives

@@ -21,7 +21,6 @@ import pytest
 from repro import api
 from repro.api import (BudgetSpec, PipelineSpec, SamplerSpec, SpecError,
                        TenantSpec, TopologySpec)
-from repro.data import stream as S
 from repro.query.registry import QueryRegistry
 
 X = 3
@@ -126,15 +125,14 @@ def test_admit_retire_equivalence_mesh():
     a, b = _tenant("alpha", _reg_a()), _tenant("beta", _reg_b())
     c = _tenant("gamma", _reg_a())
     rng = np.random.default_rng(3)
-    T, M = 3, 512
-    vals = rng.normal(50, 9, (T, M)).astype(np.float32)
-    strs = rng.integers(0, X, (T, M)).astype(np.int32)
-    counts = np.full((T,), M, np.int64)
-    batches = S.rows_to_interval_batch(vals, strs, counts, X)
+    T, N0, W = 3, 4, 128
+    rows = (rng.normal(50, 9, (T, N0, W)).astype(np.float32),
+            rng.integers(0, X, (T, N0, W)).astype(np.int32),
+            np.full((T, N0), W, np.int32))
 
     def mesh_spec(tenants):
         return PipelineSpec(
-            topology=TopologySpec(fanin=(4, 2, 1), capacity=M,
+            topology=TopologySpec(fanin=(N0, 2, 1), capacity=N0 * W,
                                   num_strata=X),
             sampler=SamplerSpec(mode="whs", backend="topk", fraction=0.25),
             tenants=tuple(tenants), seed=0)
@@ -143,10 +141,10 @@ def test_admit_retire_equivalence_mesh():
     state = pipe.init()
     pipe, state = pipe.admit(state, c)
     pipe, state = pipe.retire(state, "beta")
-    state, w_churn = pipe.run_epoch(state, pipe.default_key, batches)
+    state, w_churn = pipe.run_epoch(state, pipe.default_key, *rows)
 
     fresh = api.compile(mesh_spec((a, c)), mesh=mesh)
-    _, w_fresh = fresh.run_epoch(fresh.init(), fresh.default_key, batches)
+    _, w_fresh = fresh.run_epoch(fresh.init(), fresh.default_key, *rows)
 
     assert pipe.tenant_names == ("alpha", "gamma")
     np.testing.assert_array_equal(np.asarray(w_churn.answers),

@@ -149,7 +149,6 @@ def test_mesh_epoch_compiles_with_vma_check(v5e, monkeypatch, backend,
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from repro.api import spmd
-    from repro.core.types import IntervalBatch, StratumMeta
     from repro.kernels.fused_level_tick import ops as ft_ops
     from repro.kernels.sample_mask import ops as sm_ops
     from repro.kernels.sketch_update import ops as sk_ops
@@ -170,23 +169,23 @@ def test_mesh_epoch_compiles_with_vma_check(v5e, monkeypatch, backend,
         tenants=(api.TenantSpec.from_registry("a", reg),) if tenants
         else ()), mesh=mesh)
     rep, dev = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
-    item = NamedSharding(mesh, P(None, "data"))
+    node = NamedSharding(mesh, P(None, "data"))
     sds = lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
     f32 = lambda shape, sh: jax.ShapeDtypeStruct(shape, jnp.float32,
                                                  sharding=sh)
-    batch = IntervalBatch(
-        f32((t, m), item), jax.ShapeDtypeStruct((t, m), jnp.int32,
-                                                sharding=item),
-        jax.ShapeDtypeStruct((t, m), jnp.bool_, sharding=item),
-        StratumMeta(f32((t, X), rep), f32((t, X), rep)))
+    # the epoch's node rows: edge node d's buffer on chip d
+    rows = (f32((t, NODES, m // NODES), node),
+            jax.ShapeDtypeStruct((t, NODES, m // NODES), jnp.int32,
+                                 sharding=node),
+            jax.ShapeDtypeStruct((t, NODES), jnp.int32, sharding=node))
     key = sds(jax.eval_shape(lambda: pipe.default_key), rep)
     if tenants:
         st = jax.eval_shape(pipe.init)
         state = st._replace(tick=sds(st.tick, rep), qstate=jax.tree.map(
             lambda a: sds(a, dev), st.qstate))
-        lowered = pipe._fn.lower(state, key, f32((), rep), batch)
+        lowered = pipe._fn.lower(state, key, f32((), rep), *rows)
     else:
-        lowered = pipe._fn.lower(key, batch)
+        lowered = pipe._fn.lower(key, *rows)
     assert spmd._check_vma(backend)
     assert "tpu_custom_call" in lowered.compile().as_text()
 

@@ -223,9 +223,9 @@ def test_windowed_and_decayed_lower_through_spmd_plan():
         mu = 5.0 if t < 6 else 50.0
         rows_v[t] = rng.normal(mu, 0.5, 64).astype(np.float32)
         rows_s[t] = np.arange(64) % 2
-    batch = S.rows_to_interval_batch(rows_v, rows_s, counts, 2)
     state = pipe.init()
-    state, wa = pipe.run_epoch(state, pipe.default_key, batch)
+    state, wa = pipe.run_epoch(state, pipe.default_key, rows_v[:, None],
+                               rows_s[:, None], counts[:, None])
     last = pipe.rows(wa)[-1]
     wq = float(pipe.answer(last["answers"], "wq")[0])
     dhh = float(pipe.answer(last["answers"], "dhh")[0])
